@@ -14,8 +14,9 @@ execution engines, and gate on what the data plane ENFORCES:
 
 The replay is fully deterministic (seeded streams, seeded training, CPU
 math), so these are structural gates, not timing gates — they run hard
-in CI.  A forced-4-device subprocess serves the same mitigated pipeline
-through ``ShardedPacketServeEngine`` for the shards > 1 row, and a
+in CI.  The same mitigated pipeline serves through
+``ShardedPacketServeEngine`` on four devices for the shards > 1 row
+(``common.sharded_stats``), and a
 rate-limit run hot-swaps the detector MID-mitigation to pin the
 swap-while-limited contract.  All rows consolidate into BENCH_serve.json
 via benchmarks.run.
@@ -26,7 +27,6 @@ via benchmarks.run.
 from __future__ import annotations
 
 import os
-import textwrap
 
 import numpy as np
 
@@ -39,8 +39,8 @@ from repro.telemetry import Telemetry
 from benchmarks.common import (
     RESULTS_DIR,
     render_table,
-    run_sharded_probe,
     save_result,
+    sharded_stats,
 )
 
 # the operator event journal of the replay (drift/swap/mitigation/SLO
@@ -90,14 +90,11 @@ def serve_once(pipe, stream, *, depth: int = 2, telemetry=False):
     return v, eng
 
 
-_SHARDED_SCRIPT = textwrap.dedent(f"""
-    import json
+def sharded_run() -> dict:
+    """The mitigated syn_flood replay through ``ShardedPacketServeEngine``
+    on four devices -> its stats; the action tables must drop packets."""
     import jax
-    import numpy as np
-    from benchmarks.attack_defense import (BATCH, N_PACKETS, REPLAY_SEED,
-                                           build_pipeline)
-    from repro.data import traffic
-    from repro.flowstate import MITIGATED, StatefulPipeline
+
     from repro.serve import ShardedPacketServeEngine
 
     assert len(jax.devices()) == 4, jax.devices()
@@ -114,8 +111,7 @@ _SHARDED_SCRIPT = textwrap.dedent(f"""
             dropped += int((v == MITIGATED).sum())
     assert dropped > 0, "sharded replay mitigated nothing"
     assert int(eng.state.mitigated_flows) > 0
-    print("SHARDED-STATS " + json.dumps(eng.stats()))
-""")
+    return eng.stats()
 
 
 def _stat_row(stats: dict, pipeline: str, engine: str) -> dict:
@@ -231,11 +227,12 @@ def main() -> dict:
     swap = swap_under_rate_limit()
     print(f"\nswap under rate-limit: {swap}")
 
-    sharded = run_sharded_probe(_SHARDED_SCRIPT)
-    assert sharded["shards"] > 1, \
-        f"sharded probe degraded to {sharded['shards']} shard"
-    serve_stats.append(_stat_row(sharded, "mitigate-syn_flood",
-                                 "ShardedPacketServeEngine"))
+    sharded = sharded_stats("benchmarks.attack_defense", "sharded_run")
+    if sharded is not None:
+        assert sharded["shards"] > 1, \
+            f"sharded run degraded to {sharded['shards']} shard"
+        serve_stats.append(_stat_row(sharded, "mitigate-syn_flood",
+                                     "ShardedPacketServeEngine"))
 
     print("\n== serving-engine stats (BENCH_serve entries) ==")
     print(render_table(

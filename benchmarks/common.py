@@ -1,8 +1,9 @@
 """Shared benchmark utilities: result table rendering, JSON persistence,
-and the forced-multi-device subprocess probe the sharded serving rows use."""
+and where the sharded serving rows run (``sharded_stats``)."""
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import subprocess
@@ -16,16 +17,41 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SHARDED_MARKER = "SHARDED-STATS "
 
 
+def sharded_stats(module: str, fn: str, *, n_devices: int = 4
+                  ) -> dict | None:
+    """The stats dict ``module.fn()`` returns — a run of
+    ``ShardedPacketServeEngine`` over ``n_devices`` devices — measured
+    with one process per chip.
+
+    On an accelerator host the call runs in this process on
+    ``jax.devices()`` (a child could not reach a chip this process
+    holds); with fewer than ``n_devices`` devices there is no sharded row
+    (None).  A CPU parent runs it in a child with ``n_devices`` forced
+    host devices (``run_sharded_probe``)."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        if len(jax.devices()) < n_devices:
+            return None
+        return getattr(importlib.import_module(module), fn)()
+    return run_sharded_probe(
+        f"import json\nfrom {module} import {fn}\n"
+        f"print({SHARDED_MARKER!r} + json.dumps({fn}()))",
+        n_devices=n_devices)
+
+
 def run_sharded_probe(script: str, *, n_devices: int = 4,
                       timeout: int = 900) -> dict:
-    """Run ``script`` in a subprocess with ``n_devices`` forced host CPU
-    devices (``--xla_force_host_platform_device_count``, the same trick as
-    ``tests/test_sharded_engine.py``) so ``ShardedPacketServeEngine`` rows
-    in BENCH_serve.json record REAL multi-device runs — the ``shards``
-    field then carries the actual device count instead of the one-device
-    degradation.  The script must print one line
+    """Run ``script`` in a CPU-only subprocess with ``n_devices`` forced
+    host devices (``--xla_force_host_platform_device_count``, the same
+    trick as ``tests/test_sharded_engine.py``) so ``ShardedPacketServeEngine``
+    rows in BENCH_serve.json record real multi-device runs — the
+    ``shards`` field then carries the actual device count instead of the
+    one-device degradation.  ``JAX_PLATFORMS=cpu`` is set explicitly, so
+    the child never reaches for a chip.  The script must print one line
     ``SHARDED-STATS {json}``; returns the parsed dict."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={n_devices}")
     env["PYTHONPATH"] = os.pathsep.join(

@@ -39,8 +39,8 @@ from benchmarks.common import (
     bench_pps,
     bench_pps_best,
     render_table,
-    run_sharded_probe,
     save_result,
+    sharded_stats,
 )
 
 BATCHES = (256, 1024, 4096)
@@ -105,38 +105,28 @@ def _serve_stat(pipeline, d, *, label: str, engine_cls=PacketServeEngine,
     }
 
 
-_SHARDED_DAG_SCRIPT = """
-import json
-import jax
-assert len(jax.devices()) == 4, jax.devices()
-from benchmarks.dag_throughput import _leaf, _serve_stat, build_chain
-from repro.core import chaining
-from repro.serve import ShardedPacketServeEngine
-d, node, pipes = build_chain()
-stat = _serve_stat(
-    chaining.compile_dag(_leaf("ad") > _leaf("tc"), pipes,
-                         backend="pallas"),
-    d, label="ad>tc", engine_cls=ShardedPacketServeEngine)
-assert stat["shards"] == 4, stat
-print("SHARDED-STATS " + json.dumps(stat))
-"""
+def sharded_run() -> dict:
+    """The ad>tc DAG served by ``ShardedPacketServeEngine`` on four
+    devices -> its BENCH_serve row."""
+    import jax
+
+    assert len(jax.devices()) == 4, jax.devices()
+    d, _node, pipes = build_chain()
+    stat = _serve_stat(
+        chaining.compile_dag(_leaf("ad") > _leaf("tc"), pipes,
+                             backend="pallas"),
+        d, label="ad>tc", engine_cls=ShardedPacketServeEngine)
+    assert stat["shards"] == 4, stat
+    return stat
 
 
-def _sharded_serve_stat(d, pipes) -> dict:
-    """The ShardedPacketServeEngine row, measured in a forced-4-device
-    subprocess so ``shards`` records the actual device count (an
-    in-process run on a one-device host degrades to the base engine and
-    would claim a sharded number it never earned).  Falls back to the
-    honest degraded in-process row if the probe cannot run."""
-    try:
-        return run_sharded_probe(_SHARDED_DAG_SCRIPT)
-    except Exception as e:  # noqa: BLE001 — probe is environment-bound
-        print(f"sharded probe unavailable ({e}); recording the "
-              f"in-process (degraded) row")
-        return _serve_stat(
-            chaining.compile_dag(_leaf("ad") > _leaf("tc"), pipes,
-                                 backend="pallas"),
-            d, label="ad>tc", engine_cls=ShardedPacketServeEngine)
+def _sharded_serve_stat() -> dict | None:
+    """The ShardedPacketServeEngine row on four devices
+    (``common.sharded_stats``), so ``shards`` records the actual device
+    count — a run on one device degrades to the base engine and would
+    claim a sharded number it never earned.  None (no row) on an
+    accelerator host with fewer than four chips."""
+    return sharded_stats("benchmarks.dag_throughput", "sharded_run")
 
 
 def bench_fused_dag(d, pipes) -> dict:
@@ -319,8 +309,10 @@ def main() -> dict:
                     label="ad>tc"),
         _serve_stat(chaining.compile_dag(ad_tc, pipes, backend="pallas"), d,
                     label="ad>tc"),
-        _sharded_serve_stat(d, pipes),
     ]
+    sharded = _sharded_serve_stat()
+    if sharded is not None:
+        serve_stats.append(sharded)
     print("\n== serving-engine stats (BENCH_serve entries) ==")
     print(render_table(
         serve_stats,

@@ -4,9 +4,10 @@ Builds the streaming DDoS-burst pipeline (per-flow registers + DNN
 classifier, examples/stream_flows.py) and measures end-to-end serving
 throughput through ``PacketServeEngine`` on both execution engines, plus
 the reaction-time report (packets until each attack flow's first correct
-verdict) that the stateless serving path cannot produce at all.  A
-forced-4-device subprocess then serves the same stream through
-``ShardedPacketServeEngine`` so BENCH_serve.json carries a real
+verdict) that the stateless serving path cannot produce at all.  The
+same stream then serves through ``ShardedPacketServeEngine`` on four
+devices (``common.sharded_stats``: the chips of an accelerator host, or
+forced host devices in a CPU child) so BENCH_serve.json carries a real
 ``shards > 1`` stateful row.
 
 Asserts (the flow-state contract's performance gate):
@@ -23,8 +24,6 @@ Asserts (the flow-state contract's performance gate):
 
 from __future__ import annotations
 
-import textwrap
-
 import numpy as np
 
 from repro.core import codegen, mlalgos
@@ -32,7 +31,7 @@ from repro.data import traffic
 from repro.flowstate import StatefulPipeline
 from repro.serve.packet_engine import PacketServeEngine
 
-from benchmarks.common import render_table, run_sharded_probe, save_result
+from benchmarks.common import render_table, save_result, sharded_stats
 
 N_PACKETS = 16_000
 N_SLOTS = 2048
@@ -43,9 +42,9 @@ REPEATS = 3
 FUSED_FLOW_GATE = 3.0
 
 
-def build_pipeline():
+def build_pipeline(n_slots: int = N_SLOTS):
     train = traffic.make_stream("ddos_burst", n_packets=N_PACKETS, seed=0)
-    stages, names = traffic.flow_feature_stages(n_slots=N_SLOTS)
+    stages, names = traffic.flow_feature_stages(n_slots=n_slots)
     ds, mu, sd = traffic.stream_feature_dataset(train, stages, names,
                                                 sample_every=2)
     dnn = mlalgos.train_dnn(ds, hidden=[16, 8], epochs=3, seed=0)
@@ -55,7 +54,7 @@ def build_pipeline():
     return list(stages) + suffix
 
 
-def build_mat_pipeline(*, mitigated: bool = False):
+def build_mat_pipeline(*, mitigated: bool = False, n_slots: int = N_SLOTS):
     """The same flow prefix capped with a range-table (MAT-form) suffix —
     ``Quantize -> LUTGather -> Reduce -> LabelMap`` — and, for the
     ``mitigate-fused`` row, a trailing ``Mitigate`` action table.  Both
@@ -63,7 +62,7 @@ def build_mat_pipeline(*, mitigated: bool = False):
     from repro.core import stageir
     from repro.flowstate import MitigationSpec
 
-    (fk, ru, ws), _names = traffic.flow_feature_stages(n_slots=N_SLOTS)
+    (fk, ru, ws), _names = traffic.flow_feature_stages(n_slots=n_slots)
     rng = np.random.default_rng(7)
     n_in = ws.n_out
     edges = np.sort(rng.random((n_in, 7)).astype(np.float32), axis=1)
@@ -74,7 +73,7 @@ def build_mat_pipeline(*, mitigated: bool = False):
               stageir.LabelMap(np.asarray([0, 1, 1, 0], np.int32))]
     if mitigated:
         stages.append(stageir.Mitigate(
-            MitigationSpec(n_slots=N_SLOTS, threshold=6)))
+            MitigationSpec(n_slots=n_slots, threshold=6)))
     return stages
 
 
@@ -144,14 +143,13 @@ def fused_suffix_rows(stream) -> tuple[list[dict], list[dict]]:
     return rows, stats_rows
 
 
-# serves the SAME stream through ShardedPacketServeEngine under 4 forced
-# host devices (run_sharded_probe) — the shards>1 stateful trajectory row
-_SHARDED_SCRIPT = textwrap.dedent(f"""
-    import json
+def sharded_run() -> dict:
+    """Serve the flow-ddos stream through ``ShardedPacketServeEngine`` on
+    four devices -> its stats.  One warm pass compiles the shard_map
+    step; the same engine then serves the stream ``REPEATS`` more times
+    so the recorded stats amortize the compile out of the rate."""
     import jax
-    from benchmarks.flow_throughput import N_PACKETS, build_pipeline
-    from repro.data import traffic
-    from repro.flowstate import StatefulPipeline
+
     from repro.serve import ShardedPacketServeEngine
 
     assert len(jax.devices()) == 4, jax.devices()
@@ -161,22 +159,20 @@ _SHARDED_SCRIPT = textwrap.dedent(f"""
     eng = ShardedPacketServeEngine(pipe, feature_dim=len(traffic.COLUMNS),
                                    max_batch=512)
     assert eng.sharded and eng.n_shards == 4, (eng.sharded, eng.n_shards)
-    # one warm pass compiles the shard_map step; the SAME engine then
-    # serves the stream {REPEATS} more times so the recorded stats
-    # amortize the compile out of the steady-state rate
-    for _ in range(1 + {REPEATS}):
+    for _ in range(1 + REPEATS):
         for _v in eng.serve_stream(stream.chunks(512)):
             pass
-    print("SHARDED-STATS " + json.dumps(eng.stats()))
-""")
+    return eng.stats()
 
 
-def sharded_stateful_stat() -> dict:
+def sharded_stateful_stat() -> dict | None:
     """One BENCH_serve entry for the fused stateful pipeline served by
-    ``ShardedPacketServeEngine`` across 4 forced host devices; the
-    ``shards`` field records the actual device count of the run."""
-    s = run_sharded_probe(_SHARDED_SCRIPT)
-    assert s["shards"] > 1, f"sharded probe degraded to {s['shards']} shard"
+    ``ShardedPacketServeEngine`` on four devices (None on an accelerator
+    host with fewer); ``shards`` records the actual device count."""
+    s = sharded_stats("benchmarks.flow_throughput", "sharded_run")
+    if s is None:
+        return None
+    assert s["shards"] > 1, f"sharded run degraded to {s['shards']} shard"
     return {
         "engine": "ShardedPacketServeEngine",
         "pipeline": "flow-ddos",
@@ -263,8 +259,10 @@ def main() -> dict:
     print(render_table(sfx_rows, ["pipeline", "interp_pps", "pallas_pps",
                                   "speedup"]))
 
-    # multi-device stateful trajectory row (forced-4-device subprocess)
-    serve_stats.append(sharded_stateful_stat())
+    # multi-device stateful trajectory row
+    sharded = sharded_stateful_stat()
+    if sharded is not None:
+        serve_stats.append(sharded)
     print("\n== serving-engine stats (BENCH_serve entries) ==")
     print(render_table(
         serve_stats,

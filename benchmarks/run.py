@@ -7,10 +7,11 @@ After the selected benches run, the per-engine serving stats recorded by
 ``dag_throughput`` / ``flow_throughput`` are consolidated into
 ``benchmarks/results/BENCH_serve.json`` — the machine-readable perf
 trajectory (pkt/s + p50/p95/p99 latency per engine x backend) future PRs
-diff throughput against.  ``ShardedPacketServeEngine`` rows are measured
-in forced-multi-device subprocesses (``common.run_sharded_probe``), so
-their ``shards`` field records the actual device count of the run — one
-stateless (ad>tc) and one stateful (flow-ddos, fused launch) row.
+diff throughput against.  ``ShardedPacketServeEngine`` rows run on four
+devices (``common.sharded_stats``: in-process on a four-chip host,
+forced host devices in a CPU child otherwise), so their ``shards`` field
+records the actual device count of the run.  A failure to consolidate
+fails the run.
 """
 
 from __future__ import annotations
@@ -94,6 +95,9 @@ def write_bench_serve() -> str | None:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     names = sys.argv[1:] or list(BENCHES)
     summary = []
     for name in names:
@@ -109,13 +113,17 @@ def main() -> None:
         summary.append((name, status, time.perf_counter() - t0))
 
     # ALWAYS consolidate, even when benches failed their gates: every
-    # bench saves its artifact BEFORE asserting (the PR-6 convention), so
-    # the trajectory refreshes from whatever measurements exist
+    # bench saves its artifact BEFORE asserting, so the trajectory
+    # refreshes from whatever measurements exist
+    t0 = time.perf_counter()
     try:
         serve_path = write_bench_serve()
-    except Exception:  # noqa: BLE001 — the trajectory is best-effort
+        status = "ok"
+    except Exception as e:  # noqa: BLE001 — reported, and fails the run
         traceback.print_exc()
         serve_path = None
+        status = f"FAIL {type(e).__name__}: {e}"
+    summary.append(("BENCH_serve", status, time.perf_counter() - t0))
     if serve_path:
         print(f"\nconsolidated serving stats -> {serve_path}")
 

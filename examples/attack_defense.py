@@ -20,10 +20,13 @@ controller would use.
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import codegen, feasibility as feas, mlalgos, stageir
 from repro.data import traffic
 from repro.flowstate import MITIGATED, MitigationSpec, StatefulPipeline
 from repro.serve.packet_engine import PacketServeEngine
+
+enable_compile_cache()
 
 N_PACKETS = 8_000
 N_SLOTS = 1024

@@ -26,6 +26,7 @@ benign bulk traffic:
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import codegen, dse, mlalgos
 from repro.core.alchemy import Platforms
 from repro.core.traincache import GLOBAL_CACHE
@@ -36,6 +37,8 @@ from repro.serve import (
     PacketServeEngine,
     ShardedPacketServeEngine,
 )
+
+enable_compile_cache()
 
 CHUNK = 512
 N_PACKETS = 24_000

@@ -27,6 +27,7 @@ import tempfile
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import codegen, mlalgos, stageir
 from repro.data import traffic
 from repro.flowstate import (
@@ -37,6 +38,8 @@ from repro.flowstate import (
     StatefulPipeline,
 )
 from repro.serve import HotSwapController, PacketServeEngine
+
+enable_compile_cache()
 
 CHUNK = 512
 N_PACKETS = 12_000

@@ -16,7 +16,10 @@ import numpy as np
 
 import homunculus
 from homunculus.alchemy import DataLoader, Model, Platforms
+from repro.compile_cache import enable_compile_cache
 from repro.data import netdata
+
+enable_compile_cache()
 
 
 @DataLoader

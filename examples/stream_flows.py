@@ -17,10 +17,13 @@ correct verdict).
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import codegen, feasibility as feas, mlalgos
 from repro.data import traffic
 from repro.flowstate import StatefulPipeline
 from repro.serve.packet_engine import PacketServeEngine
+
+enable_compile_cache()
 
 N_SLOTS = 2048
 N_PACKETS = 16_000

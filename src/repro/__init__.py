@@ -1,3 +1,1 @@
 """repro: JAX/Pallas reproduction of Homunculus data-plane ML pipelines."""
-
-from repro import _compat  # noqa: F401  (jax forward-compat polyfills)

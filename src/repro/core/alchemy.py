@@ -118,9 +118,10 @@ def passthrough_iomap(features, upstream):
 #   * the truth-tested Seq is an unnamed temporary (CPython refcount ==
 #     eval stack + bool arg + getrefcount arg), so a variable-bound Seq
 #     (``s = a > b; if s: ...``) never records; and
-#   * the truth-test executes at a JUMP_IF_*_OR_POP opcode — the implicit
-#     ``and`` of a chained comparison — so ``if a > b: ...`` (POP_JUMP_*)
-#     and ``bool(a > b)`` (CALL) never record either;
+#   * the truth-test executes at the implicit ``and`` of a chained
+#     comparison — JUMP_IF_*_OR_POP up to CPython 3.11, ``COPY 1;
+#     POP_JUMP_IF_FALSE`` on 3.12 — so ``if a > b: ...`` (a bare
+#     POP_JUMP_*) and ``bool(a > b)`` (CALL) never record either;
 # and the record is consume-once, cleared by the next ``>``.  Both rails
 # are CPython-specific; ``_natural_chain_selfcheck`` probes the behavior
 # at import and warns (advising parentheses) where it does not hold.
@@ -140,11 +141,20 @@ class _ChainState(threading.local):
 
 _CHAIN = _ChainState()
 _CHAIN_DEPTH = 8    # pathological-nesting backstop
-_TEMP_REFS = 3      # CPython refcount of a stack temporary seen by __bool__
+_OPMAP = __import__("dis").opmap
 _CHAIN_OPS = frozenset(
-    op for name, op in __import__("dis").opmap.items()
+    op for name, op in _OPMAP.items()
     if name in ("JUMP_IF_FALSE_OR_POP", "JUMP_IF_TRUE_OR_POP")
 )
+# CPython 3.12 dropped those opcodes: the chain's implicit and is
+# ``COPY 1; POP_JUMP_IF_FALSE``, and the COPY holds one more stack
+# reference on the truth-tested temporary.  (3.13 truth-tests at TO_BOOL,
+# which no rail matches: the self-checks warn there.)
+_COPY_FORM = not _CHAIN_OPS and "COPY" in _OPMAP and "TO_BOOL" not in _OPMAP
+if _COPY_FORM:
+    _CHAIN_OPS = frozenset({_OPMAP["POP_JUMP_IF_FALSE"]})
+# CPython refcount of a stack temporary seen by __bool__
+_TEMP_REFS = 4 if _COPY_FORM else 3
 
 
 def _chain_window():
@@ -152,38 +162,40 @@ def _chain_window():
     return (frame_id, lasti, jump_target) — the consuming ``__gt__`` must
     run in that frame strictly inside (lasti, target].  None = not a chain.
 
-    Two bytecode checks make this precise on CPython <= 3.11:
-      * the current opcode is the chain's JUMP_IF_*_OR_POP; and
-      * the jump targets a ``ROT_TWO; POP_TOP`` cleanup block — ONLY
-        chained comparisons emit that epilogue; a plain ``and``/``or``
+    Two bytecode checks make this precise on CPython <= 3.12:
+      * the current opcode is the chain's JUMP_IF_*_OR_POP (on 3.12, a
+        POP_JUMP_IF_FALSE right after ``COPY 1``); and
+      * the jump targets a ``ROT_TWO``/``SWAP 2; POP_TOP`` cleanup block —
+        ONLY chained comparisons emit that epilogue; a plain ``and``/``or``
         jumps to the end of its expression instead, so value-producing
         conjunctions like ``(a > b) and f(b > c)`` never record.
-    Bytecode eras without the dedicated opcode (CPython 3.12) and
-    non-CPython frame layouts degrade to a permissive window (refcount
-    rail only; the import-time self-checks warn there)."""
+    Bytecode eras with no recognised chain opcode and non-CPython frame
+    layouts degrade to a permissive window (refcount rail only; the
+    import-time self-checks warn there)."""
     if not _CHAIN_OPS:
         return (None, 0, sys.maxsize)
     try:
         import dis
 
         f = sys._getframe(2)
-        code = f.f_code.co_code
-        if code[f.f_lasti] not in _CHAIN_OPS:
+        if f.f_code.co_code[f.f_lasti] not in _CHAIN_OPS:
             return None
-        target = next(
-            (i.argval for i in dis.get_instructions(f.f_code)
-             if i.offset == f.f_lasti),
-            None,
-        )
-        if target is None:
+        ins = [i for i in dis.get_instructions(f.f_code)
+               if i.opname != "EXTENDED_ARG"]
+        at = {i.offset: k for k, i in enumerate(ins)}
+        k = at.get(f.f_lasti)
+        if k is None:
             return (id(f), f.f_lasti, sys.maxsize)
-        rot_two = dis.opmap.get("ROT_TWO")
-        pop_top = dis.opmap.get("POP_TOP")
-        if rot_two is not None and pop_top is not None:
-            if not (target + 2 < len(code)
-                    and code[target] == rot_two
-                    and code[target + 2] == pop_top):
-                return None     # an and/or jump, not a chain epilogue
+        if _COPY_FORM and not (k > 0 and ins[k - 1].opname == "COPY"
+                               and ins[k - 1].arg == 1):
+            return None         # a statement jump: the value is not kept
+        target = ins[k].argval
+        t = at.get(target)
+        if not (t is not None and t + 1 < len(ins)
+                and (ins[t].opname == "ROT_TWO"
+                     or (ins[t].opname == "SWAP" and ins[t].arg == 2))
+                and ins[t + 1].opname == "POP_TOP"):
+            return None         # an and/or jump, not a chain epilogue
         return (id(f), f.f_lasti, target)
     except Exception:  # pragma: no cover - permissive on odd interpreters
         return (None, 0, sys.maxsize)

@@ -286,6 +286,7 @@ class FPGAModel:
 @dataclasses.dataclass
 class TPUModel:
     vmem_bytes: int = 64 * 2**20          # VMEM working-set budget
+    hbm_bytes: int = 16 * 2**30           # device memory (v5e: 16 GiB)
     peak_flops: float = 197e12            # bf16
     hbm_bw: float = 819e9
     batch: int = 256                       # serving batch per launch
@@ -334,10 +335,15 @@ def flowstate_report(spec, platform_kind: str = "taurus", model: Any = None
     calibrations above)."""
     from repro.core.stageir import flowstate_specs, spec_params
 
+    from repro.kernels.flow_update import envelope_reason, vmem_bytes
+
     words = spec_params(flowstate_specs(spec))
     return _register_table_report(
         words, platform_kind, model, what="flow registers",
-        tpu_vmem=lambda m: _flow_update_vmem(spec, m),
+        tpu=lambda m: (vmem_bytes(spec.width, m.batch),
+                       _tpu_table_bytes(spec.n_slots, spec.width),
+                       envelope_reason(spec.n_slots, spec.width,
+                                       len(spec.hist_sizes))),
     )
 
 
@@ -352,34 +358,39 @@ def mitigation_report(spec, platform_kind: str = "taurus", model: Any = None
     charged through the SAME per-platform register model and composed via
     ``FeasibilityReport.merge`` — mitigation SRAM is never free.  On the
     TPU target the action table FOLDS INTO the fused flow launch
-    (``kernels/fused_flow._mitigation_phase``), so the charge is the
-    kernel's actual resident set: the table (keys + [hits, since] rows)
-    plus the seven per-batch mitigation operand columns the launch
-    stages into VMEM (worst case — the shared-segmentation fast path
-    ships only the table pair)."""
+    (``kernels/fused_flow.mitigation_phase``): the table stays in HBM and
+    the launch stages the batch's mitigation columns into VMEM."""
     from repro.core.stageir import mitigation_specs, spec_params
+    from repro.kernels.flow_update import envelope_reason
+    from repro.kernels.flow_update.kernel import LANE
 
     words = spec_params(mitigation_specs(spec))
     return _register_table_report(
         words, platform_kind, model, what="mitigation registers",
-        # table + the 7 per-batch [B] operand columns of the fused
-        # mitigation phase (keys/valid/rank/seg_slot + verdict gather),
-        # matching kernels.fused_flow.vmem_bytes' mit term
-        tpu_vmem=lambda m: words * 4 + m.batch * 7 * 4,
+        # the six lane-padded [B, 1] mitigation columns (double-buffered)
+        # plus the [B, B] verdict permutation of the fused launch
+        tpu=lambda m: (2 * 6 * m.batch * LANE * 4 + m.batch ** 2 * 4,
+                       _tpu_table_bytes(spec.n_slots, spec.width),
+                       envelope_reason(spec.n_slots, spec.width, 0)),
     )
 
 
-def _flow_update_vmem(spec, m) -> int:
-    from repro.kernels.flow_update import vmem_bytes as flow_vmem
-
-    return flow_vmem(spec.n_slots, spec.width, m.batch)
+def _tpu_table_bytes(n_slots: int, width: int) -> int:
+    """HBM bytes of one register table on the TPU: f32 rows padded to
+    whole 8-word groups plus the int32 key vector.  The v5e serving
+    step's ``memory_analysis`` stores the 28-word flow-ddos rows as 32
+    words (narrower rows may take less: this is an upper bound); donated,
+    the table is updated in place."""
+    return n_slots * (-(-width // 8) * 8 + 1) * 4
 
 
 def _register_table_report(words: int, platform_kind: str, model: Any, *,
-                           what: str, tpu_vmem) -> FeasibilityReport:
+                           what: str, tpu) -> FeasibilityReport:
     """Shared per-platform charging for one register table of ``words``
     32-bit words (stored keys included) — the flow-state detection table
-    and the mitigation action table go through the same rules."""
+    and the mitigation action table go through the same rules.  ``tpu(m)``
+    gives the TPU charge: (kernel VMEM working set, table HBM bytes, the
+    kernel envelope's refusal or None)."""
     nbytes = words * 4
     reasons: list[str] = []
 
@@ -428,15 +439,22 @@ def _register_table_report(words: int, platform_kind: str, model: Any, *,
         )
     if platform_kind == "tpu":
         m = model or TPUModel()
-        vmem = tpu_vmem(m)
+        vmem, hbm, refusal = tpu(m)
+        if refusal:
+            reasons.append(f"{what}: {refusal}")
         if vmem > m.vmem_bytes:
             reasons.append(
                 f"{what} need {vmem} B VMEM > {m.vmem_bytes} budget"
             )
+        if hbm > m.hbm_bytes:
+            reasons.append(
+                f"{what} need {hbm} B HBM > {m.hbm_bytes} budget"
+            )
         launch = m.launch_overhead_us * 1e-6
         return FeasibilityReport(
             feasible=not reasons, reasons=reasons,
-            resources={"vmem_bytes": vmem, "register_words": words},
+            resources={"vmem_bytes": vmem, "hbm_bytes": hbm,
+                       "register_words": words},
             latency_ns=launch * 1e9,
             throughput_pps=m.batch / launch,
         )

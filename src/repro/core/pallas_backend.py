@@ -23,8 +23,8 @@ prep — is folded into the kernel's input transform):
 
 Stateful prefixes (``FlowKey RegisterUpdate``, the flow-state contract)
 lower through ``lower_stateful_pallas`` onto kernels/flow_update — the
-whole hash -> gather -> update -> scatter dataflow as ONE kernel launch
-with the register table resident in VMEM.
+hash -> gather -> update -> scatter dataflow around ONE kernel launch,
+with the register table in HBM and only the batch's rows in VMEM.
 
 Everything else (``CentroidDistance``, ``TreeTraverse``, out-of-envelope
 shapes) returns ``None`` and the caller falls back to the interpreter —
@@ -138,18 +138,15 @@ def _match_mat(stages: list[Stage]):
 
 
 def _in_envelope_mlp(weights) -> bool:
-    from repro.kernels.fused_mlp import LANE
+    from repro.kernels.fused_mlp import envelope_reason
 
-    widths = [int(weights[0].shape[0])] + [int(w.shape[1]) for w in weights]
-    return max(widths) <= LANE
+    return envelope_reason(int(weights[0].shape[0]), weights) is None
 
 
 def _in_envelope_mat(tables, lmap) -> bool:
-    from repro.kernels import mat_lut as mat_ops
+    from repro.kernels.mat_lut import envelope_reason
 
-    F, bins, C = tables.shape
-    return (F <= mat_ops.MAX_FEATURES and bins <= mat_ops.MAX_BINS
-            and C <= mat_ops.LANE and lmap.shape[0] <= mat_ops.LANE)
+    return envelope_reason(tables, lmap) is None
 
 
 def pallas_eligible(stages: list[Stage]) -> bool:
@@ -453,11 +450,14 @@ def stateful_eligible(prefix: list[Stage]) -> bool:
     if len(prefix) != 2 or not isinstance(prefix[0], FlowKey) \
             or not isinstance(prefix[1], RegisterUpdate):
         return False
+    return _table_envelope_reason(prefix[1].spec) is None
+
+
+def _table_envelope_reason(spec) -> str | None:
     from repro.kernels import flow_update as fu
 
-    spec = prefix[1].spec
-    return (spec.n_slots <= fu.MAX_SLOTS and spec.width <= fu.MAX_WIDTH
-            and len(spec.hist_sizes) <= fu.MAX_HISTS)
+    return fu.envelope_reason(spec.n_slots, spec.width,
+                              len(spec.hist_sizes))
 
 
 def lower_stateful(prefix: list[Stage], backend: str
@@ -530,9 +530,10 @@ def lower_stateful_pallas(prefix: list[Stage]) -> Callable | None:
 #
 # The whole stateful pipeline — FlowKey -> RegisterUpdate -> feature-emit
 # -> classifier [-> Mitigate] — as ONE Pallas launch (kernels/fused_flow):
-# register table(s), the classifier parameters AND the mitigation action
-# table co-resident in VMEM, feature rows consumed in-kernel, only int32
-# verdicts and the updated tables leaving.  The fused envelope covers
+# the batch's table rows, the classifier parameters AND the action-table
+# update co-resident in VMEM, feature rows consumed in-kernel, only the
+# post-update rows and int32 verdicts leaving (the tables stay in HBM).
+# The fused envelope covers
 # MLP, MAT (Quantize -> LUTGather -> Reduce -> [LabelMap]) and
 # CentroidDistance suffixes, plus multi-table DAGs (several FlowKey /
 # RegisterUpdate groups feeding one classifier).  StatefulPipeline tries
@@ -605,8 +606,9 @@ def _plan_fused(prefix_or_groups, suffix: list[Stage], mitigation=None):
     modes, n_in = [], 0
     for fk, ru, ws in groups:
         spec = ru.spec
-        if not stateful_eligible([fk, ru]):
-            return None, "flow table outside the flow_update envelope"
+        reason = _table_envelope_reason(spec)
+        if reason:
+            return None, reason
         if spec.width > FF_LANE:
             return None, "register width exceeds the kernel lane"
         if ws is None:
@@ -623,8 +625,9 @@ def _plan_fused(prefix_or_groups, suffix: list[Stage], mitigation=None):
     if mitigation is not None:
         from repro.kernels import flow_update as fu
 
-        if mitigation.spec.n_slots > fu.MAX_SLOTS:
-            return None, "mitigation table outside the kernel envelope"
+        reason = fu.envelope_reason(mitigation.spec.n_slots, 2, 0)
+        if reason:
+            return None, "mitigation: " + reason
 
     mlp = _match_mlp(body)
     if mlp is not None:

@@ -111,7 +111,8 @@ class Dense(Stage):
     kind = "dense"
 
     def apply(self, h):
-        out = h @ jnp.asarray(self.w, jnp.float32) + jnp.asarray(
+        out = jnp.dot(h, jnp.asarray(self.w, jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST) + jnp.asarray(
             self.b, jnp.float32
         )
         if self.act == "relu":
@@ -135,13 +136,16 @@ class FusedMLP(Stage):
     kind = "fused_mlp"
 
     def apply(self, h):
-        from repro.kernels.fused_mlp import fused_mlp
+        from repro.kernels.fused_mlp import envelope_reason, fused_mlp
+        from repro.kernels.fused_mlp.ref import mlp_ref
 
-        return fused_mlp(
-            h,
-            [jnp.asarray(w) for w in self.weights],
-            [jnp.asarray(b) for b in self.biases],
-        )
+        ws = [jnp.asarray(w) for w in self.weights]
+        bs = [jnp.asarray(b) for b in self.biases]
+        # the interpreter's walk: beyond the kernel's lane tile the
+        # stage is plain jnp (the Pallas lowering declines such models)
+        if envelope_reason(h.shape[1], ws):
+            return mlp_ref(h, ws, bs)
+        return fused_mlp(h, ws, bs)
 
     def meta(self):
         return {
@@ -165,13 +169,14 @@ class FusedClassify(Stage):
     kind = "fused_classify"
 
     def apply(self, h):
-        from repro.kernels.fused_mlp import fused_mlp_classify
+        from repro.kernels.fused_mlp import envelope_reason, fused_mlp_classify
+        from repro.kernels.fused_mlp.ref import mlp_ref
 
-        return fused_mlp_classify(
-            h,
-            [jnp.asarray(w) for w in self.weights],
-            [jnp.asarray(b) for b in self.biases],
-        )
+        ws = [jnp.asarray(w) for w in self.weights]
+        bs = [jnp.asarray(b) for b in self.biases]
+        if envelope_reason(h.shape[1], ws):
+            return jnp.argmax(mlp_ref(h, ws, bs), -1).astype(jnp.int32)
+        return fused_mlp_classify(h, ws, bs)
 
     def meta(self):
         return FusedMLP(self.weights, self.biases).meta()

@@ -34,8 +34,10 @@ def pipeline_apply(stage_fn, stage_params: jax.Array, x: jax.Array, *,
     def body(w_local, x_full):
         w = w_local[0]
         p = jax.lax.axis_index(axis)
-        recv = jnp.zeros(x_full.shape[1:], x_full.dtype)
-        outs = jnp.zeros_like(x_full)
+        # the carry differs per stage once the first tick has run
+        recv, outs = jax.lax.pcast(
+            (jnp.zeros(x_full.shape[1:], x_full.dtype),
+             jnp.zeros_like(x_full)), (axis,), to="varying")
 
         def tick(carry, t):
             recv, outs = carry

@@ -28,9 +28,10 @@ The batch scan is ORDER-DEPENDENT (a later packet may evict an earlier
 packet's slot), so the reference here runs as a ``fori_loop`` over the
 batch — shared jnp code on every execution engine.  Under
 ``backend="pallas"`` the action table FOLDS INTO the fused flow launch
-(``kernels/fused_flow._mitigation_phase``: the [hits, since] row rides
+(``kernels/fused_flow.mitigation_phase``: the [hits, since] row rides
 the same segmented lockstep-rounds + drain schedule as the detection
-table, the drop decision is one masked lane over the int32 verdicts), so
+table, and the drop decision rewrites the verdict of the packet being
+stepped), so
 a mitigated pipeline reports ``"pallas-fused-flow"``; slots never
 interact, so the fused phase is bit-identical to this scan by the same
 per-slot decomposition that pins the flow tables.  When the rest of the

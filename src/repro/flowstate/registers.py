@@ -222,8 +222,9 @@ def update_flows(
 ) -> tuple[FlowState, jax.Array]:
     """One batched register update -> (new state, per-packet feature rows).
 
-    ``backend="pallas"`` runs the fused scatter/gather kernel (one launch,
-    table resident in VMEM); ``"interpret"`` the jitted jnp scan.  Both are
+    ``backend="pallas"`` runs the flow-update kernel (one launch; the
+    table stays in HBM, only the batch's rows enter the kernel);
+    ``"interpret"`` the jitted jnp scan.  Both are
     bit-identical (shared per-packet step) and preserve arrival order."""
     from repro.kernels import flow_update as fu
 

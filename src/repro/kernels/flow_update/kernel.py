@@ -1,4 +1,4 @@
-"""Pallas kernel: batched flow-state scatter/gather update in ONE launch.
+"""Pallas kernel: batched flow-state update in ONE launch.
 
 The jnp reference (ref.py) walks the batch packet-by-packet — the update is
 order-dependent (EWMAs are non-commutative, collisions evict), so a naive
@@ -7,47 +7,49 @@ semantics do give: REGISTER SLOTS NEVER INTERACT.  Each slot's final state
 is a function of its own packets' subsequence only, so the sequential loop
 factorizes into per-slot chains.
 
-The wrapper (ops.py) pre-SEGMENTS the batch: a stable sort by slot turns
-every per-slot chain into a contiguous run, preserving per-slot arrival
-order (stable sort), and hands the kernel the segment tables
-(``seg_first/seg_len/seg_slot``) plus each packet's ``rank`` within its
-chain.  The kernel then runs a hybrid, exact schedule:
+The wrapper (ops.py) pre-SEGMENTS the batch in an XLA prelude: a stable
+sort by slot turns every per-slot chain into a contiguous run (stable, so
+per-slot arrival order is preserved).  In that SORTED order the state a
+packet sees is the row its chain predecessor — the previous sorted row —
+left behind, or, for a chain head (``rank == 0``), the table row itself.
+The prelude therefore hands the kernel, per sorted packet:
 
-  1. COMPACTED LOCKSTEP ROUNDS — round r applies, simultaneously for
-     every occupied segment, that segment's (r+1)-th packet.  The active
-     rows are gathered once into a compacted [B]-sized table (cost
-     independent of the slot count), updated with the same elementwise
-     f32 expressions as the reference's ``_packet_step``, and scattered
-     back after the last round.  Within a round all targets are distinct
-     segments; across rounds each segment sees its packets in arrival
-     order.  Runs ``min(max_rank + 1, PAR_ROUNDS)`` rounds.
+  * ``init`` — the table row at the packet's slot (read by chain heads);
+  * ``fresh`` — whether the packet evicts: a head's stored key differs
+    from its own, or a mid-chain key change (two flows sharing a slot);
+  * ``add`` / ``val`` — the full-width update terms (counter increments
+    plus histogram bumps; EWMA values);
+  * ``rank`` — its position in the chain (``-1`` for padding rows).
 
-  2. UNROLLED SEQUENTIAL DRAIN — the deep-chain remainder
-     (``rank >= PAR_ROUNDS``) replays against the full table with the
-     same per-packet expressions as the reference's ``_packet_step``,
-     statically unrolled ``DRAIN_UNROLL`` packets per loop trip with the
-     operand slicing hoisted to the block and the feature-row emit
-     buffered (one store per trip) — the dispatch overhead that dominates
-     the plain scan is amortized away.  ``drain_order`` (from the
-     wrapper) lists those packets in sorted-segment order — per slot that
-     extends the round order exactly — padded with a sentinel row whose
-     ``valid == 0``, so over-stepping past ``n_rem`` is a no-op.
+The table itself never enters the kernel: its rows are gathered by the
+prelude and the chain tails are scattered back by the epilogue, so the
+table lives in HBM and the kernel's VMEM working set depends on the batch
+only.  The kernel runs an exact two-phase walk over ``[B, W]`` rows:
 
-Both phases respect per-slot arrival order and use the SAME per-slot
-arithmetic in the same order as ``_packet_step``, so state, features and
-verdicts are **bit-identical** to ``flow_update_ref`` by the per-slot
-decomposition — the conformance suite pins this over random
-collision-heavy batches.  Feature rows come out in SORTED order; the
-wrapper applies the inverse permutation to restore arrival order.
+  1. LOCKSTEP ROUNDS — round r applies, simultaneously for every chain,
+     the packet of rank r: its predecessor row is the previous sorted row
+     (one sublane rotate of the whole state block) or ``init``.  Within a
+     round all targets are distinct chains; across rounds each chain sees
+     its packets in arrival order.  Runs ``min(max_rank + 1, PAR_ROUNDS)``
+     rounds.
+  2. SEQUENTIAL DRAIN — the deep-chain remainder (``rank >= PAR_ROUNDS``)
+     replays one packet at a time in sorted order, each a scalar-indexed
+     ``[1, W]`` row load and store (positions from SMEM).
 
-``_flow_phase`` is the schedule factored over plain jnp values so the
-fused stateful kernel (kernels/fused_flow) can run the identical update
-phase and feed the feature rows straight into its classifier matmuls
-without leaving VMEM.
+Both phases apply the same elementwise f32 expressions, in the same order,
+as the reference's ``_packet_step``, so state, features and verdicts are
+**bit-identical** to ``flow_update_ref`` by the per-slot decomposition —
+the conformance suite pins this over random collision-heavy batches.  The
+output rows are the post-update rows in SORTED order: they are at once
+each packet's feature row and, at each chain's tail, the slot's new state.
 
-Grid: (1,) — rounds are a sequential dependency chain; everything is a
-full VMEM-resident block.  VMEM working set = S*(W+1) words + batch rows
-(``vmem_bytes``), which feasibility checks against the platform budget.
+``walk`` is the schedule over refs, shared with the fused stateful kernel
+(kernels/fused_flow), which runs the identical flow update and the
+mitigation action table on it.
+
+Grid: (1,) — rounds are a sequential dependency chain; every operand is
+one VMEM block.  Schedule scalars and drain positions ride in SMEM
+(scalar prefetch).  ``vmem_bytes`` is the working set feasibility checks.
 """
 
 from __future__ import annotations
@@ -57,279 +59,141 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flow_update.ref import ewma_blend
 
 LANE = 128
-# ranks executed as compacted lockstep rounds before the schedule switches
-# to the unrolled sequential drain (crossover: one round costs ~a dozen
+SUBLANE = 8
+# chain ranks retired by the vectorized lockstep rounds before the
+# schedule switches to the sequential drain (one round costs ~a dozen
 # [B, W] vector ops, one drained packet ~a dozen [1, W] ops)
 PAR_ROUNDS = 8
-# packets replayed per drain-loop trip; the static unroll amortizes the
-# while-loop dispatch overhead that dominates a packet-at-a-time scan
-DRAIN_UNROLL = 8
+# on-chip memory a launch may claim: the compiler's scoped limit is
+# raised to the kernel's own estimate, capped here (a v5e TensorCore
+# has 128 MiB of VMEM)
+VMEM_LIMIT = 96 * 2**20
 
 
-def _flow_phase(keys, regs, pk, upd, bins, valid, rank, seg_first, seg_len,
-                seg_slot, drain_order, drain_sid, deep_src, *,
-                n_counters: int, n_ewma: int, alpha: float):
-    """The hybrid update schedule over plain jnp values.
+def walk(sched_ref, drain_ref, rank, state_refs, init, step,
+         extra_refs=()):
+    """Run the lockstep-rounds + drain schedule over sorted-order refs.
 
-    keys [S] i32; regs [S, W] f32; the batch operands are [B_pad]-sized and
-    SORTED by slot (stable, so per-slot arrival order is preserved), with
-    at least one trailing sentinel row (``valid == 0``, ``bins == -1``).
-    ``seg_first/seg_len/seg_slot[k]`` describe segment k (0 for padding
-    entries past the live segment count, which carry ``seg_len == 0``);
-    ``drain_order`` lists the ``rank >= PAR_ROUNDS`` packets in sorted
-    order and ``drain_sid`` their rows in the deep table, both padded
-    with a sentinel index; ``deep_src`` [D] maps deep-table rows back to
-    segment ids (the last row is the drain sentinel).
+    ``sched_ref`` (SMEM) holds ``[n_rounds, n_drain]``; ``drain_ref``
+    (SMEM) the sorted positions of the ``rank >= PAR_ROUNDS`` packets in
+    sorted order; ``rank`` is the ``[B, 1]`` chain-rank column.
+    ``state_refs`` are ``[B, w]`` refs holding each packet's post-update
+    state (zero-initialised by the caller), ``init`` the rows a chain
+    head starts from.  ``step(prev, rows)`` maps the predecessor state
+    (list of arrays) and a row selector (``slice(None)`` for a whole
+    round, ``pl.ds(p, 1)`` for one drained packet) to ``(new_state,
+    extras)``; extras land in ``extra_refs`` at the same rows."""
+    def round_body(r, carry):
+        cur = [s[...] for s in state_refs]
+        prev = [jnp.where(rank == 0, i, pltpu.roll(c, 1, 0))
+                for i, c in zip(init, cur)]
+        new, extras = step(prev, slice(None))
+        hit = rank == r
+        for s, c, n in zip(state_refs, cur, new):
+            s[...] = jnp.where(hit, n, c)
+        for e, v in zip(extra_refs, extras):
+            e[...] = jnp.where(hit, v, e[...])
+        return carry
 
-    -> (keys' [S], regs' [S, W], feats [B_pad, W] in SORTED order)."""
-    S, W = regs.shape
-    B = pk.shape[0]
-    C, E = n_counters, n_ewma
-    n_hists = bins.shape[1]
-    live = valid != 0
+    jax.lax.fori_loop(0, sched_ref[0], round_body, 0)
 
-    n_rounds = jnp.minimum(
-        jnp.max(jnp.where(live, rank, 0)) + 1, PAR_ROUNDS
-    )
-    col = jax.lax.broadcasted_iota(jnp.int32, (B, W), 1)
+    def drain_body(i, carry):
+        p = drain_ref[i]
+        prev = [s[pl.ds(p - 1, 1), :] for s in state_refs]
+        new, extras = step(prev, pl.ds(p, 1))
+        for s, n in zip(state_refs, new):
+            s[pl.ds(p, 1), :] = n
+        for e, v in zip(extra_refs, extras):
+            e[pl.ds(p, 1), :] = v
+        return carry
 
-    # precompute every packet's full-width update terms ONCE, vectorized
-    # over the batch — the sequential phases then just gather rows:
-    #   add_full[i] = counter increments + hist one-hot bumps.  Counter,
-    #     EWMA and hist columns are DISJOINT, so each column sums at most
-    #     one nonzero term and folding them into one additive tensor is
-    #     exact (same bits as the reference's sequential adds);
-    #   val_full[i] = EWMA set-values padded to full width.
-    add_full = jnp.pad(upd[:, :C], ((0, 0), (0, W - C)))
-    for j in range(n_hists):                     # static unroll per hist
-        add_full = add_full + (col == bins[:, j:j + 1]).astype(jnp.float32)
-    val_full = jnp.pad(upd[:, C:C + E], ((0, 0), (C, W - C - E)))
-    col1 = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
-    m_ewma = (col1 >= C) & (col1 < C + E)        # [1, W], broadcasts
-
-    # gather each live segment's row ONCE into a compacted [B]-sized active
-    # table; rounds update the compacted copy (cost independent of S)
-    seg_slot_c = jnp.where(seg_len > 0, seg_slot, 0)
-    act_keys = keys[seg_slot_c]                  # [B]
-    act_regs = regs[seg_slot_c]                  # [B, W]
-    feats0 = jnp.zeros((B, W), jnp.float32)
-
-    def round_body(state):
-        r, ak, ar, feats = state
-        ok = r < seg_len                         # segment still has packets
-        pid = jnp.where(ok, seg_first + r, 0)    # this round's packet ids
-        key_r = pk[pid]
-        add_r = add_full[pid]
-        val_r = val_full[pid]
-
-        # identical per-slot arithmetic to ref._packet_step, vectorized
-        # across segments (elementwise f32: bit-identical per element)
-        fresh = ak != key_r                      # evict-on-collision
-        row0 = jnp.where(fresh[:, None], jnp.zeros_like(ar), ar)
-        ewma = jnp.where(fresh[:, None], val_r,
-                         ewma_blend(row0, val_r, alpha))
-        new = jnp.where(m_ewma, ewma, row0) + add_r
-
-        ar = jnp.where(ok[:, None], new, ar)
-        ak = jnp.where(ok, key_r, ak)
-        # this round's packets read their segment's post-round row
-        feats = feats.at[jnp.where(ok, pid, B)].set(new, mode="drop")
-        return r + 1, ak, ar, feats
-
-    _, act_keys, act_regs, feats = jax.lax.while_loop(
-        lambda s: s[0] < n_rounds, round_body,
-        (jnp.int32(0), act_keys, act_regs, feats0),
-    )
-
-    # unrolled sequential drain: deep-chain packets (rank >= PAR_ROUNDS)
-    # replay in sorted order — per slot that extends the round order
-    # exactly — against a DOUBLY-COMPACTED table holding only the deep
-    # segments' rows (at most B/(PAR_ROUNDS+1) of them): each step's row
-    # load/store then slices a cache-sized [D, W] buffer (a full-table
-    # dynamic-update would copy S rows per packet, and the active table
-    # still B).  Operands are pre-gathered into drain order so each trip
-    # block-slices them contiguously, and feature rows accumulate in a
-    # drain-order buffer written back with ONE scatter at the end.
-    # Over-stepping past n_rem lands on the sentinel entry (valid == 0,
-    # deep row D-1), which writes the stored values back and emits a zero
-    # feature row.
-    rem = live & (rank >= PAR_ROUNDS)
-    n_rem = jnp.sum(rem.astype(jnp.int32))
-    trips = (n_rem + DRAIN_UNROLL - 1) // DRAIN_UNROLL
-    pk_d = pk[drain_order]                       # [B] drain-ordered
-    add_d = add_full[drain_order]                # [B, W] precomputed terms
-    val_d = val_full[drain_order]
-    valid_d = valid[drain_order]
-    deep_keys = act_keys[deep_src]               # [D]
-    deep_regs = act_regs[deep_src]               # [D, W]
-    dfeats0 = jnp.zeros((B, W), jnp.float32)
-
-    def drain_step(u, pk_b, sid_b, add_b, val_b, valid_b, ak2, ar2):
-        """One packet against the active table — the same elementwise f32
-        expressions as ref._packet_step, minus its per-packet operand
-        slicing (hoisted to the block), update-term construction (the
-        precomputed add_full/val_full rows) and feats scatter (buffered)."""
-        sid = sid_b[u]
-        key = pk_b[u:u + 1, None]                # [1, 1]
-        stored = jax.lax.dynamic_slice(ak2, (sid, 0), (1, 1))
-        row = jax.lax.dynamic_slice(ar2, (sid, 0), (1, W))
-        fresh = stored != key
-        row0 = jnp.where(fresh, jnp.zeros_like(row), row)
-        val_u = val_b[u:u + 1]
-        ewma = jnp.where(fresh, val_u, ewma_blend(row0, val_u, alpha))
-        new = jnp.where(m_ewma, ewma, row0) + add_b[u:u + 1]
-        ok = valid_b[u:u + 1, None] != 0
-        new_row = jnp.where(ok, new, row)
-        ak2 = jax.lax.dynamic_update_slice(
-            ak2, jnp.where(ok, key, stored), (sid, 0))
-        ar2 = jax.lax.dynamic_update_slice(ar2, new_row, (sid, 0))
-        return ak2, ar2, jnp.where(ok, new_row, jnp.zeros_like(new_row))
-
-    def drain_body(state):
-        t, ak2, ar2, dfeats = state
-        base = t * DRAIN_UNROLL
-        pk_b = jax.lax.dynamic_slice(pk_d, (base,), (DRAIN_UNROLL,))
-        sid_b = jax.lax.dynamic_slice(drain_sid, (base,), (DRAIN_UNROLL,))
-        add_b = jax.lax.dynamic_slice(
-            add_d, (base, 0), (DRAIN_UNROLL, W))
-        val_b = jax.lax.dynamic_slice(
-            val_d, (base, 0), (DRAIN_UNROLL, W))
-        valid_b = jax.lax.dynamic_slice(valid_d, (base,), (DRAIN_UNROLL,))
-        out = []
-        for u in range(DRAIN_UNROLL):            # static unroll
-            ak2, ar2, frow = drain_step(
-                u, pk_b, sid_b, add_b, val_b, valid_b, ak2, ar2)
-            out.append(frow)
-        dfeats = jax.lax.dynamic_update_slice(
-            dfeats, jnp.concatenate(out, axis=0), (base, 0))
-        return t + 1, ak2, ar2, dfeats
-
-    _, deep_keys2, deep_regs, dfeats = jax.lax.while_loop(
-        lambda s: s[0] < trips, drain_body,
-        (jnp.int32(0), deep_keys[:, None], deep_regs, dfeats0),
-    )
-    deep_keys = deep_keys2[:, 0]
-    # sentinel drain entries all write zero rows onto the sentinel row,
-    # which the wrapper slices off; live entries are distinct positions
-    feats = feats.at[drain_order].set(dfeats, mode="drop")
-
-    # fold the drained deep rows back into the active table (only the
-    # live deep rows; junk copies and the sentinel row drop out of range)
-    n_deep_segs = jnp.sum((seg_len > PAR_ROUNDS).astype(jnp.int32))
-    d_idx = jnp.arange(deep_src.shape[0], dtype=jnp.int32)
-    src_tgt = jnp.where(d_idx < n_deep_segs, deep_src, B)
-    act_keys = act_keys.at[src_tgt].set(deep_keys, mode="drop")
-    act_regs = act_regs.at[src_tgt].set(deep_regs, mode="drop")
-
-    # scatter the compacted rows back; padding segments drop out of range
-    tgt = jnp.where(seg_len > 0, seg_slot, S)
-    keys = keys.at[tgt].set(act_keys, mode="drop")
-    regs = regs.at[tgt].set(act_regs, mode="drop")
-    return keys, regs, feats
+    jax.lax.fori_loop(0, sched_ref[1], drain_body, 0)
 
 
-def _kernel(keys_ref, regs_ref, pk_ref, upd_ref, bins_ref, valid_ref,
-            rank_ref, segf_ref, segl_ref, segs_ref, dord_ref, dsid_ref,
-            dsrc_ref, keys_out, regs_out, feats_out, *,
-            n_counters: int, n_ewma: int, n_hists: int, alpha: float):
-    """keys_ref [S, Kw] i32; regs_ref [S, W_pad] f32; batch refs are
-    [B_pad, *]-shaped and slot-sorted (see ``_flow_phase``).  Only column 0
-    of the narrow int refs is live (rest is tile padding); only the first
-    ``n_hists`` bins columns are real."""
-    keys, regs, feats = _flow_phase(
-        keys_ref[...][:, 0],
-        regs_ref[...],
-        pk_ref[...][:, 0],
-        upd_ref[...],
-        bins_ref[...][:, :max(n_hists, 1)],
-        valid_ref[...][:, 0],
-        rank_ref[...][:, 0],
-        segf_ref[...][:, 0],
-        segl_ref[...][:, 0],
-        segs_ref[...][:, 0],
-        dord_ref[...][:, 0],
-        dsid_ref[...][:, 0],
-        dsrc_ref[...][:, 0],
-        n_counters=n_counters, n_ewma=n_ewma, alpha=alpha,
-    )
-    k_w = keys_out.shape[1]
-    keys_out[...] = jnp.pad(keys[:, None], ((0, 0), (0, k_w - 1)))
-    regs_out[...] = regs
-    feats_out[...] = feats
+def flow_phase(sched_ref, drain_ref, init_ref, add_ref, val_ref, rank_ref,
+               fresh_ref, x_ref, *, n_counters: int, n_ewma: int,
+               alpha: float):
+    """The register update of one table over sorted-order refs; leaves
+    each packet's post-update row in ``x_ref`` (zero for padding)."""
+    W = x_ref.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+    m_ewma = (col >= n_counters) & (col < n_counters + n_ewma)
+
+    def step(prev, rows):
+        fresh = fresh_ref[rows, :] != 0
+        val = val_ref[rows, :]
+        # the reference's _packet_step, elementwise: evict-on-collision,
+        # EWMA set-or-blend, counter/hist increments (disjoint columns,
+        # so one additive term per column)
+        row0 = jnp.where(fresh, jnp.zeros_like(prev[0]), prev[0])
+        ewma = jnp.where(fresh, val, ewma_blend(row0, val, alpha))
+        return [jnp.where(m_ewma, ewma, row0) + add_ref[rows, :]], []
+
+    x_ref[...] = jnp.zeros_like(x_ref)
+    walk(sched_ref, drain_ref, rank_ref[...], [x_ref], [init_ref[...]],
+         step)
+
+
+def block(shape):
+    """Whole-array VMEM block for a grid=(1,) launch (index maps take the
+    scalar-prefetch refs as trailing arguments)."""
+    return pl.BlockSpec(shape, lambda i, *_, _n=len(shape): (0,) * _n)
+
+
+def compiler_params(nbytes: int):
+    """Mosaic parameters: the scoped-VMEM limit raised to the launch's
+    own working-set estimate (``nbytes``), within ``VMEM_LIMIT``."""
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(max(2 * nbytes, 32 * 2**20), VMEM_LIMIT)))
 
 
 @functools.partial(
-    jax.jit, static_argnames=("n_counters", "n_ewma", "n_hists", "alpha",
-                              "interpret")
+    jax.jit, static_argnames=("n_counters", "n_ewma", "alpha", "interpret")
 )
 def flow_update_padded(
-    keys: jax.Array,        # [S, Kw] int32 (-1 = empty; col 0 live)
-    regs: jax.Array,        # [S, W_pad] f32
-    pkt_keys: jax.Array,    # [B_pad, Kw] int32, slot-sorted
-    upd: jax.Array,         # [B_pad, U_pad] f32, slot-sorted
-    bins: jax.Array,        # [B_pad, H_pad] int32 absolute cols (-1 = none)
-    valid: jax.Array,       # [B_pad, Kw] int32 (sentinel rows 0)
-    rank: jax.Array,        # [B_pad, Kw] int32 position within slot chain
-    seg_first: jax.Array,   # [B_pad, Kw] int32 segment start positions
-    seg_len: jax.Array,     # [B_pad, Kw] int32 segment lengths (0 = pad)
-    seg_slot: jax.Array,    # [B_pad, Kw] int32 segment target slots
-    drain_order: jax.Array,  # [B_pad, Kw] int32 deep-packet replay order
-    drain_sid: jax.Array,    # [B_pad, Kw] int32 deep-packet deep-table rows
-    deep_src: jax.Array,     # [D, Kw] int32 deep-table row -> segment id
+    sched: jax.Array,       # [2] int32 (n_rounds, n_drain)          SMEM
+    drain: jax.Array,       # [B_pad] int32 drain positions, sorted  SMEM
+    init: jax.Array,        # [B_pad, W_pad] f32 table row at the slot
+    add: jax.Array,         # [B_pad, W_pad] f32 counter/hist increments
+    val: jax.Array,         # [B_pad, W_pad] f32 EWMA values
+    rank: jax.Array,        # [B_pad, 1] int32 chain rank (-1 = padding)
+    fresh: jax.Array,       # [B_pad, 1] int32 1 = evict before update
     *,
     n_counters: int,
     n_ewma: int,
-    n_hists: int,
     alpha: float,
     interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """-> (keys' [S, Kw], regs' [S, W_pad], feats [B_pad, W_pad] sorted)."""
-    S, k_w = keys.shape
-    _, w_pad = regs.shape
-    B = pkt_keys.shape[0]
-    assert S & (S - 1) == 0, "slot count must be a power of two"
-    narrow = pl.BlockSpec((B, k_w), lambda i: (0, 0))
+) -> jax.Array:
+    """-> post-update rows [B_pad, W_pad] f32 in SORTED order."""
+    b_pad, w_pad = init.shape
     return pl.pallas_call(
-        functools.partial(
-            _kernel, n_counters=n_counters, n_ewma=n_ewma,
-            n_hists=n_hists, alpha=alpha,
+        functools.partial(flow_phase, n_counters=n_counters,
+                          n_ewma=n_ewma, alpha=alpha),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[block(init.shape), block(add.shape),
+                      block(val.shape), block(rank.shape),
+                      block(fresh.shape)],
+            out_specs=block((b_pad, w_pad)),
         ),
-        grid=(1,),
-        in_specs=[
-            # sequential round chain: every operand is one resident block
-            pl.BlockSpec((S, k_w), lambda i: (0, 0)),
-            pl.BlockSpec((S, w_pad), lambda i: (0, 0)),
-            narrow,
-            pl.BlockSpec((B, upd.shape[1]), lambda i: (0, 0)),
-            pl.BlockSpec((B, bins.shape[1]), lambda i: (0, 0)),
-            narrow, narrow, narrow, narrow, narrow, narrow, narrow,
-            pl.BlockSpec((deep_src.shape[0], k_w), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((S, k_w), lambda i: (0, 0)),
-            pl.BlockSpec((S, w_pad), lambda i: (0, 0)),
-            pl.BlockSpec((B, w_pad), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((S, k_w), jnp.int32),
-            jax.ShapeDtypeStruct((S, w_pad), jnp.float32),
-            jax.ShapeDtypeStruct((B, w_pad), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((b_pad, w_pad), jnp.float32),
+        compiler_params=compiler_params(vmem_bytes(w_pad, b_pad)),
         interpret=interpret,
-    )(keys, regs, pkt_keys, upd, bins, valid, rank, seg_first, seg_len,
-      seg_slot, drain_order, drain_sid, deep_src)
+    )(sched, drain, init, add, val, rank, fresh)
 
 
-def vmem_bytes(n_slots: int, width: int, batch: int = 256) -> int:
-    """VMEM working set the kernel claims (feasibility input): the whole
-    register file (rows + keys), the batch's packet/update/feature rows,
-    the compacted active table, and the int32 scheduling operands
-    (keys/valid/rank/segment tables/drain order + hist bins)."""
-    table = n_slots * (width + 1) * 4
-    batch_rows = batch * (width + 1) * 4 * 3   # upd in + feats out + active
-    aux = batch * 4 * 16                       # scheduling ints + hist bins
-    return table + batch_rows + aux
+def vmem_bytes(width: int, batch: int = 256) -> int:
+    """VMEM working set of one table's flow phase (feasibility input),
+    at TPU tiling: three [B, W] operand blocks (init, add, val) and the
+    output block, every row lane-padded to 128, plus the two [B, 1] int
+    columns (rank, fresh) padded to a full lane tile, all double-buffered
+    by the pipeline.  The table is not resident — it stays in HBM."""
+    w_pad = -(-width // LANE) * LANE
+    b_pad = -(-batch // SUBLANE) * SUBLANE
+    return 2 * (4 * b_pad * w_pad + 2 * b_pad * LANE) * 4

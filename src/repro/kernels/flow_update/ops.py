@@ -1,38 +1,33 @@
-"""Public op: batched flow-register update, fused scatter/gather form.
+"""Public op: batched flow-register update, one kernel launch.
 
 ``flow_update(keys, regs, pkt_keys, upd, bins, valid)`` segments the batch
-by slot, pads to tile widths, launches the Pallas kernel (interpret=True on
-CPU — the TPU path is the same kernel compiled by Mosaic) and restores
+by slot, gathers each packet's slot row, launches the Pallas kernel
+(interpret=True on CPU — the TPU path is the same kernel compiled by
+Mosaic), scatters each chain's last row back into the table and restores
 arrival order.  This is the executable artifact the Pallas serving backend
 (core.pallas_backend.lower_stateful_pallas) emits for the stateful stage
 prefix ``FlowKey -> RegisterUpdate``.
 
-Falls back to the jnp scan reference when the table is outside the kernel
-envelope (too many slots/too wide a row for resident VMEM).  Padding is
-self-masking: padded register columns start zero and are never addressed
-(absolute hist columns < W, counter/EWMA sections are static slices), so
-the real columns are bit-identical to the unpadded reference.
+The table stays in HBM: the prelude gathers the ``[B]`` rows the batch
+touches and the epilogue scatters ``[B]`` rows back (in place when the
+serving step donates the table), so the kernel's VMEM working set depends
+on the batch alone.  ``MAX_SLOTS`` is therefore an HBM bound, not a VMEM
+one; a table outside the envelope is refused here and declined, with a
+reason, by the lowering (``core.pallas_backend``) — it never silently
+takes the reference path.  Padding is self-masking: padded register
+columns start zero and are never addressed (absolute hist columns < W,
+counter/EWMA sections are static slices), so the real columns are
+bit-identical to the unpadded reference.
 
 Slot segmentation (``segment_batch``, shared with kernels/fused_flow): a
 STABLE argsort by slot makes every per-slot chain contiguous while
 preserving per-slot arrival order, so each packet's rank within its chain
-falls out of a cumulative max in O(B log B) — no [B, B] intermediates —
-and deep same-slot bursts become dense segments the kernel's lockstep
-rounds and unrolled drain both walk efficiently.  The inverse permutation
-restores arrival-order feature rows; the table update itself is
-order-independent across slots, so sorting never changes the final state.
-
-Schedule choice: the hybrid kernel covers every traffic shape — lockstep
-rounds retire interleaved traffic, and the doubly-compacted drain replays
-deep chains at a small fixed cost per packet (a [1, W] row move against a
-cache-sized deep table), measured well under the reference walk's
-per-packet cost even on a fully-degenerate single-chain batch.  The
-kernel therefore serves every in-envelope batch; the scan reference
-remains only for shapes outside the VMEM envelope.  A pure schedule
-choice either way: every path computes identical bits.
-(``telemetry.flow_health`` still flags drain-heavy batches — more than
-7/8 of live packets deeper than ``PAR_ROUNDS`` — as a traffic-shape
-signal; it is no longer a routing decision.)
+falls out of a cumulative max in O(B log B) — no [B, B] intermediates.
+The inverse permutation restores arrival-order feature rows; the table
+update itself is order-independent across slots, so sorting never changes
+the final state.  (``telemetry.flow_health`` flags drain-heavy batches —
+more than 7/8 of live packets deeper than ``PAR_ROUNDS`` — as a
+traffic-shape signal; it is not a routing decision.)
 """
 
 from __future__ import annotations
@@ -45,12 +40,20 @@ import jax.numpy as jnp
 from repro.kernels.flow_update.kernel import (
     LANE,
     PAR_ROUNDS,
+    SUBLANE,
     flow_update_padded,
 )
-from repro.kernels.flow_update.ref import flow_update_ref, hash_slot
+from repro.kernels.flow_update.ref import hash_slot
 
-# kernel envelope: the whole table must sit in VMEM for the launch
-MAX_SLOTS = 1 << 16
+# kernel envelope.  The table lives in HBM (only the batch's rows move),
+# so no slot count is refused for VMEM: the flow-ddos serving step
+# (28-word rows, batch 512) compiles for one v5e up to 2^26 slots, 8.25
+# GiB updated in place, and 2^27 exceed the chip's HBM
+# (tests/test_tpu_compile.py pins both).  MAX_SLOTS caps tables at the
+# largest size served and checked on the chip (chip_smoke.py fills a
+# quarter of it; traffic.make_stream draws flow ids from 2^20); 2^20
+# slots of the widest row are 1 GiB.
+MAX_SLOTS = 1 << 20
 MAX_WIDTH = 256
 MAX_HISTS = 8
 
@@ -63,31 +66,38 @@ def _snap(n: int, tile: int) -> int:
     return max(tile, -(-n // tile) * tile)
 
 
+def envelope_reason(n_slots: int, width: int, n_hists: int) -> str | None:
+    """Why a table is outside the flow kernels' envelope (None = inside)."""
+    if n_slots > MAX_SLOTS:
+        return f"flow table of {n_slots} slots exceeds MAX_SLOTS={MAX_SLOTS}"
+    if width > MAX_WIDTH:
+        return f"register width {width} exceeds MAX_WIDTH={MAX_WIDTH}"
+    if n_hists > MAX_HISTS:
+        return f"{n_hists} histograms exceed MAX_HISTS={MAX_HISTS}"
+    return None
+
+
 class Segments(NamedTuple):
-    """Slot-segmented batch layout (all entries in SORTED order except
+    """Slot-segmented batch layout (entries in SORTED order except
     ``order``/``inv``, which map between arrival and sorted order)."""
 
     order: jax.Array       # [B] arrival index of sorted position i
     inv: jax.Array         # [B] sorted position of arrival index p
-    rank: jax.Array        # [B] position within the slot's chain
-    seg_first: jax.Array   # [B] segment k's first sorted position
-    seg_len: jax.Array     # [B] segment k's packet count (0 = padding)
-    seg_slot: jax.Array    # [B] segment k's table slot
-    drain_order: jax.Array  # [B] rank >= PAR_ROUNDS packets, sorted; B = pad
-    drain_sid: jax.Array   # [B] those packets' deep-table rows; -1 = pad
-    deep_src: jax.Array    # [B] segment id behind each deep-table row
-    n_deep: jax.Array      # [] live packets with rank >= par_rounds
-    n_live: jax.Array      # [] live packets
+    slot: jax.Array        # [B] table slot of each sorted packet
+    rank: jax.Array        # [B] position within the slot's chain (-1 = pad)
+    tail: jax.Array        # [B] bool: last live packet of its chain
+    drain: jax.Array       # [B] sorted positions with rank >= PAR_ROUNDS
+    sched: jax.Array       # [2] (lockstep rounds, drained packets)
 
 
 def segment_batch(slot: jax.Array, valid: jax.Array, n_slots: int, *,
                   par_rounds: int = PAR_ROUNDS) -> Segments:
-    """Stable-sort the batch by slot and derive the segment tables.
+    """Stable-sort the batch by slot and derive the kernel's schedule.
 
     Stability preserves per-slot arrival order, so ranks — and therefore
     the final table state — are exactly those of the arrival-order walk.
-    Invalid rows sort last (keyed ``n_slots``) and never start or extend a
-    segment.  Runs as part of the jitted serving step."""
+    Invalid rows sort last (keyed ``n_slots``), carry rank -1 and never
+    start, extend or end a chain.  Runs as part of the jitted step."""
     B = slot.shape[0]
     live = valid != 0
     pos = jnp.arange(B, dtype=jnp.int32)
@@ -98,91 +108,98 @@ def segment_batch(slot: jax.Array, valid: jax.Array, n_slots: int, *,
     is_new = jnp.concatenate(
         [jnp.ones((1,), bool), slot_s[1:] != slot_s[:-1]]
     ) & live_s
-    seg_id = jnp.cumsum(is_new.astype(jnp.int32)) - 1
-    # rank = distance from the most recent segment head (live rows only)
+    # rank = distance from the most recent chain head (live rows only)
     rank = pos - jax.lax.cummax(jnp.where(is_new, pos, 0))
-    head_tgt = jnp.where(is_new, seg_id, B)
-    seg_first = jnp.zeros(B, jnp.int32).at[head_tgt].set(pos, mode="drop")
-    seg_slot = jnp.zeros(B, jnp.int32).at[head_tgt].set(slot_s, mode="drop")
-    seg_len = jnp.zeros(B, jnp.int32).at[
-        jnp.where(live_s, seg_id, B)
-    ].add(1, mode="drop")
+    rank = jnp.where(live_s, rank, -1)
+    nxt_ends = jnp.concatenate([is_new[1:] | ~live_s[1:],
+                                jnp.ones((1,), bool)])
+    tail = live_s & nxt_ends
     inv = jnp.zeros(B, jnp.int32).at[order].set(pos)
 
-    rem = live_s & (rank >= par_rounds)
-    remi = rem.astype(jnp.int32)
-    csum = jnp.cumsum(remi)
-    n_deep = csum[-1]
-    n_live = jnp.sum(live_s.astype(jnp.int32))
-    # stable partition (drain rows first, in sorted order) via scatter —
-    # no second argsort: drain row i lands at csum[i]-1, the rest fill
-    # the tail in order
-    dest = jnp.where(rem, csum - 1, n_deep + pos - csum)
-    packed = jnp.zeros(B, jnp.int32).at[dest].set(pos, mode="drop")
-    drain_order = jnp.where(pos < n_deep, packed, B)
-    # the drain runs against a doubly-compacted table holding only the
-    # DEEP segments (seg_len > par_rounds, so at most B/(par_rounds+1)
-    # rows): each replay step then moves [1, W] of a cache-sized buffer.
-    # drain_sid[i] = deep-table row of drain packet i (-1 = sentinel,
-    # remapped by pack_segmented_operands); deep_src[d] = segment id the
-    # deep-table row d was compacted from.
-    deep = seg_len > par_rounds
-    did = jnp.cumsum(deep.astype(jnp.int32)) - 1
-    drain_sid = jnp.where(pos < n_deep, did[seg_id[packed]], -1)
-    deep_src = jnp.zeros(B, jnp.int32).at[
-        jnp.where(deep, did, B)
-    ].set(pos, mode="drop")
-    return Segments(order, inv, rank, seg_first, seg_len, seg_slot,
-                    drain_order, drain_sid, deep_src, n_deep, n_live)
+    # deep packets, compacted in sorted order (per chain that extends the
+    # round order exactly)
+    deep = rank >= par_rounds
+    csum = jnp.cumsum(deep.astype(jnp.int32))
+    drain = jnp.zeros(B, jnp.int32).at[
+        jnp.where(deep, csum - 1, B)].set(pos, mode="drop")
+    n_rounds = jnp.clip(jnp.max(rank) + 1, 0, par_rounds)
+    sched = jnp.stack([n_rounds, csum[-1]]).astype(jnp.int32)
+    return Segments(order.astype(jnp.int32), inv, slot_s, rank, tail,
+                    drain, sched)
 
 
-def deep_rows(batch: int, tile: int, par_rounds: int = PAR_ROUNDS) -> int:
-    """Rows of the kernel's doubly-compacted deep-segment table: at most
-    ``batch // (par_rounds + 1)`` segments can be deep, plus one sentinel
-    row, snapped to the 8-row sublane tile (both CPU and TPU)."""
-    del tile
-    return _snap(batch // (par_rounds + 1) + 1, 8)
+def padded_rows(batch: int) -> int:
+    """Kernel row count for a batch: the 8-row sublane tile."""
+    return _snap(batch, SUBLANE)
 
 
-def pack_segmented_operands(seg: Segments, keys, regs, pkt_keys, upd, bins,
-                            valid, *, tile: int, w_pad: int, u_pad: int,
-                            h_pad: int):
-    """Permute the batch into sorted-segment order and pad to kernel tile
-    shapes.  Adds ``tile`` trailing sentinel rows (``valid == 0``,
-    ``bins == -1``, ``drain_order == B``) so the kernel's unrolled drain
-    can over-step past ``n_rem`` as a no-op; sentinel drain packets are
-    remapped onto the deep table's reserved last row.  Narrow int
-    operands keep column 0 live only."""
-    S = keys.shape[0]
+def chain_fresh(seg: Segments, keys_s: jax.Array, stored: jax.Array):
+    """[B] bool: does each sorted packet evict?  A chain head compares the
+    key stored in its slot, a later packet its chain predecessor's key
+    (which the predecessor just wrote) — ``keys_s`` is the sorted packet
+    keys, ``stored`` the table key at each sorted packet's slot."""
+    prev = jnp.concatenate([keys_s[:1], keys_s[:-1]])
+    return jnp.where(seg.rank == 0, stored != keys_s, prev != keys_s)
+
+
+def col(vals, b_pad: int, fill: int = 0):
+    """[B] ints -> [b_pad, 1] int32 kernel column."""
+    v = jnp.asarray(vals, jnp.int32)[:, None]
+    return jnp.pad(v, ((0, b_pad - v.shape[0]), (0, 0)),
+                   constant_values=fill)
+
+
+def rows(x, b_pad: int, w_pad: int):
+    """[B, w] -> zero-padded [b_pad, w_pad] f32 kernel block."""
+    x = jnp.asarray(x, jnp.float32)
+    return jnp.pad(x, ((0, b_pad - x.shape[0]), (0, w_pad - x.shape[1])))
+
+
+def smem_vec(vals, b_pad: int):
+    """[B] ints -> [b_pad] int32 SMEM vector."""
+    v = jnp.asarray(vals, jnp.int32)
+    return jnp.pad(v, (0, b_pad - v.shape[0]))
+
+
+def pack_flow_operands(seg: Segments, keys, regs, pkt_keys, upd, bins, *,
+                       n_counters: int, n_ewma: int, w_pad: int):
+    """One table's kernel operands, in sorted order: (sched, drain) for
+    SMEM, then (init, add, val, rank, fresh).  ``add`` folds counter
+    increments and histogram one-hots — disjoint columns, so each column
+    sums at most one nonzero term and the fold is exact; ``val`` holds the
+    EWMA set-values at their columns."""
     B = pkt_keys.shape[0]
-    b_pad = B + tile
-    d_rows = deep_rows(B, tile)
+    b_pad = padded_rows(B)
+    C, E = n_counters, n_ewma
     o = seg.order
-
-    def icol(vals, fill=0):
-        out = jnp.full((b_pad, tile), fill, jnp.int32)
-        return out.at[:B, 0].set(vals)
-
-    sid = jnp.where(seg.drain_sid < 0, d_rows - 1, seg.drain_sid)
-    take = min(d_rows, B)
-    deep_src = jnp.zeros((d_rows, tile), jnp.int32).at[:take, 0].set(
-        seg.deep_src[:take])
+    pk_s = pkt_keys[o]
+    u = upd[o]
+    b = bins[o]
+    colid = jax.lax.broadcasted_iota(jnp.int32, (B, w_pad), 1)
+    add = jnp.pad(u[:, :C], ((0, 0), (0, w_pad - C)))
+    for j in range(b.shape[1]):                  # static unroll per hist
+        add = add + (colid == b[:, j:j + 1]).astype(jnp.float32)
+    val = jnp.pad(u[:, C:C + E], ((0, 0), (C, w_pad - C - E)))
+    fresh = chain_fresh(seg, pk_s, keys[seg.slot])
     return (
-        jnp.zeros((S, tile), jnp.int32).at[:, 0].set(keys),
-        jnp.pad(regs, ((0, 0), (0, w_pad - regs.shape[1]))),
-        icol(pkt_keys[o]),
-        jnp.pad(upd[o], ((0, tile), (0, u_pad - upd.shape[1]))),
-        jnp.pad(bins[o], ((0, tile), (0, h_pad - bins.shape[1])),
-                constant_values=-1),
-        icol(valid[o]),
-        icol(seg.rank),
-        icol(seg.seg_first),
-        icol(seg.seg_len),
-        icol(seg.seg_slot),
-        icol(seg.drain_order, fill=B),
-        icol(sid, fill=d_rows - 1),
-        deep_src,
+        seg.sched,
+        smem_vec(seg.drain, b_pad),
+        rows(regs[seg.slot], b_pad, w_pad),
+        rows(add, b_pad, w_pad),
+        rows(val, b_pad, w_pad),
+        col(seg.rank, b_pad, fill=-1),
+        col(fresh, b_pad),
     )
+
+
+def scatter_tails(seg: Segments, keys, regs, pkt_keys, x):
+    """Write each chain's last post-update row (and its key) home: the
+    table after the batch.  ``x`` is the kernel's [B, W] sorted rows."""
+    S = keys.shape[0]
+    tgt = jnp.where(seg.tail, seg.slot, S)
+    keys = keys.at[tgt].set(pkt_keys[seg.order], mode="drop")
+    regs = regs.at[tgt].set(x, mode="drop")
+    return keys, regs
 
 
 def flow_update(
@@ -200,43 +217,40 @@ def flow_update(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """-> (keys' [S], regs' [S, W], feats [B, W]), one kernel launch.
 
-    Bit-identical to ``flow_update_ref`` (shared per-packet step); arrival
-    order within the batch preserved; see the flow-state contract in
-    docs/pipeline_ir.md for the eviction/ordering guarantees."""
+    Bit-identical to ``flow_update_ref`` (the same per-packet
+    arithmetic); arrival order within the batch preserved; see the
+    flow-state contract in docs/pipeline_ir.md for the eviction/ordering
+    guarantees.  Raises ``ValueError`` outside the kernel envelope."""
     if interpret is None:
         interpret = not _on_tpu()
     S, W = regs.shape
     B = int(pkt_keys.shape[0])
-    H = int(bins.shape[1]) if bins.ndim == 2 else 0
-    if S > MAX_SLOTS or W > MAX_WIDTH or H > MAX_HISTS or B == 0:
-        return flow_update_ref(
-            keys, regs, pkt_keys, upd, bins, valid,
-            n_counters=n_counters, n_ewma=n_ewma, alpha=alpha,
-        )
+    bins = jnp.asarray(bins, jnp.int32)
+    if bins.ndim != 2:
+        bins = jnp.full((B, 0), -1, jnp.int32)
+    reason = envelope_reason(S, W, int(bins.shape[1]))
+    if reason:
+        raise ValueError(reason)
+    if B == 0:
+        return keys, regs, jnp.zeros((0, W), jnp.float32)
     # CPU interpret mode snaps pads to 8-wide tiles; TPU pads the last dim
     # to the full 128 lane.
-    tile = 8 if interpret else LANE
-    w_pad = _snap(W, tile)
-    u_pad = _snap(upd.shape[1], tile)
-    h_pad = _snap(H, tile) if not interpret else max(H, 1)
-
+    w_pad = _snap(W, 8 if interpret else LANE)
     keys = jnp.asarray(keys, jnp.int32)
     regs = jnp.asarray(regs, jnp.float32)
     pkt_keys = jnp.asarray(pkt_keys, jnp.int32)
-    upd = jnp.asarray(upd, jnp.float32)
-    bins = jnp.asarray(bins, jnp.int32)
-    valid = jnp.asarray(valid, jnp.int32)
 
     # segment ONCE: the layout IS the kernel's schedule.  Padding rows
     # (valid=0) are excluded, so a ragged tail cannot fake a deep chain.
-    seg = segment_batch(hash_slot(pkt_keys, S), valid, S)
-    ops = pack_segmented_operands(
-        seg, keys, regs, pkt_keys, upd, bins, valid,
-        tile=tile, w_pad=w_pad, u_pad=u_pad, h_pad=h_pad,
+    seg = segment_batch(hash_slot(pkt_keys, S), jnp.asarray(valid), S)
+    ops = pack_flow_operands(
+        seg, keys, regs, pkt_keys, jnp.asarray(upd, jnp.float32), bins,
+        n_counters=n_counters, n_ewma=n_ewma, w_pad=w_pad,
     )
-    k_out, r_out, feats = flow_update_padded(
-        *ops, n_counters=n_counters, n_ewma=n_ewma, n_hists=H,
-        alpha=float(alpha), interpret=interpret,
-    )
-    # feats come back in sorted order: inverse-permute to arrival order
-    return k_out[:, 0], r_out[:, :W], feats[:B, :W][seg.inv]
+    x = flow_update_padded(
+        *ops, n_counters=n_counters, n_ewma=n_ewma, alpha=float(alpha),
+        interpret=interpret,
+    )[:B, :W]
+    keys, regs = scatter_tails(seg, keys, regs, pkt_keys, x)
+    # rows come back in sorted order: inverse-permute to arrival order
+    return keys, regs, x[seg.inv]

@@ -6,10 +6,8 @@ from repro.kernels.fused_flow.kernel import (
     Plan,
     SuffixPlan,
     TablePlan,
-    fused_flow_classify_padded,
     fused_flow_serve_padded,
     suffix_readout,
     suffix_verdicts,
-    vmem_bytes,
 )
 from repro.kernels.fused_flow.ops import fused_flow_classify, fused_flow_serve

@@ -1,27 +1,25 @@
 """Pallas kernel: the WHOLE stateful pipeline in ONE launch.
 
 ``FlowKey -> RegisterUpdate -> feature-emit -> classifier [-> Mitigate]``
-previously cost two dispatches (flow-update kernel writing [B, W] feature
-rows back to HBM, classifier kernel reading them again) plus a host-side
-jnp scan for the action table.  Here the post-update feature rows feed the
-classifier *inside the same kernel body* — the register table(s), the
-classifier parameters AND the mitigation action table are co-resident in
-VMEM for the launch, and only int32 verdicts (plus the updated tables)
-cross the kernel boundary.  This is the Taurus per-packet story
-(PAPERS.md): stateful features, the ML decision and the enforcement
+as one dispatch: the post-update feature rows feed the classifier *inside
+the same kernel body*, and the mitigation action table updates on the
+classifier's verdicts before they leave.  Only the post-update rows and
+int32 verdicts cross the kernel boundary.  This is the Taurus per-packet
+story (PAPERS.md): stateful features, the ML decision and the enforcement
 action as one dataplane pass.
 
-The update phase is literally ``flow_update.kernel._flow_phase`` — the
-segmented hybrid schedule (compacted lockstep rounds + doubly-compacted
-unrolled drain) — so state and features are bit-identical to the scan
-reference by the same per-slot decomposition.  The launch is described by
-a static ``Plan``:
+The tables stay in HBM.  The wrapper's XLA prelude gathers the batch's
+slot rows and the epilogue scatters each chain's last row back (see
+kernels/flow_update), so the launch's VMEM working set depends on the
+batch only.  Inside, the update phases are ``flow_update.kernel.walk`` —
+vectorized lockstep rounds over chain ranks plus a scalar-indexed drain —
+so state and features are bit-identical to the scan reference by the same
+per-slot decomposition.  The launch is described by a static ``Plan``:
 
-  * ``Plan.tables`` — one ``TablePlan`` per flow table.  A single-table
-    launch feeds the suffix in SORTED (segment) order, exactly the PR-6
-    form; a multi-table launch runs one ``_flow_phase`` per table (each
-    with its own slot segmentation), gathers every table's feature rows
-    back to ARRIVAL order in-kernel and concatenates the per-table
+  * ``Plan.tables`` — one ``TablePlan`` per flow table.  The suffix runs
+    in the FIRST table's sorted (segment) order; a multi-table launch
+    gathers every other table's rows into that order in-kernel (a
+    scalar-indexed row copy per packet) and concatenates the per-table
     readouts into one classifier input.
   * ``Plan.suffix`` — the classifier form.  ``"mlp"`` is the snapped-lane
     matmul chain (same dot shapes as the stateless fused_mlp lowering);
@@ -29,29 +27,28 @@ a static ``Plan``:
     one-hot-matmul MATs on the readout rows; ``"centroid"`` computes the
     per-centroid squared distances (zero-padded lanes contribute exact
     zeros) with the masked arg-reduce and LabelMap rewrite in-kernel.
-    ``suffix_readout``/``suffix_verdicts`` are plain-jnp and shared with
-    the wrapper's reference fallback, so every path computes identical
-    bits.
-  * ``Plan.mit`` — the folded action table.  Unlike the flow phase, the
-    [hits, since] scan admits a CLOSED FORM over each maximal same-key
-    run of a slot chain (``_mitigation_phase``): hits is a segmented
-    prefix sum of attack indicators, marked is therefore monotone within
-    a run, and since is the marked-predecessor count — so the whole
-    phase is ONE loop-free vectorized pass (cumsums + gathers), no
-    lockstep rounds, no drain.  The drop / rate-limit decision is one
-    extra masked lane over the int32 verdicts.  When the action table
-    has the SAME slot count as a single flow table, ``hash(key) &
-    (S-1)`` gives identical slots, so the launch reuses the flow table's
-    segmentation operands wholesale (``MitPlan.shared_seg``: no second
-    sort, no verdict permutation, two extra operands instead of seven).
-    Every quantity equals the arrival-order scan's value exactly
-    (integer-valued f32, exact below 2**24 like the LabelMap matvec), so
-    the result is bit-identical to
-    ``flowstate.mitigation.mitigate_update``.
+    ``suffix_readout``/``suffix_verdicts`` are plain jnp.
+  * ``Plan.mit`` — the folded action table.  Its [hits, since] scan runs
+    on the same ``walk`` schedule as the flow tables, over the action
+    table's own slot segmentation, with the mitigation reference's
+    per-packet arithmetic; the drop / rate-limit decision rewrites the
+    verdict of the packet being stepped.  When the action table has the
+    SAME slot count as a single flow table, ``hash(key) & (S-1)`` gives
+    identical slots, so the launch reuses the flow table's schedule
+    (``MitPlan.shared_seg``: no second sort, no verdict permutation).
+    Otherwise the verdicts move from the suffix's order into the action
+    table's with a one-hot matmul (exact: small integers).  Every
+    quantity is an integer-valued f32 (exact below 2**24), so the result
+    is bit-identical to ``flowstate.mitigation.mitigate_update``.
+
+Every matmul runs at ``precision=HIGHEST``: one-hot gathers then copy
+their f32 operands exactly, and the MLP suffix agrees with the XLA and
+stateless-kernel paths that the contract compares it with.
 
 Grid: (1,) — the update phases are sequential dependency chains; every
-operand is a full VMEM-resident block (``vmem_bytes`` is the feasibility
-claim).
+operand is one VMEM block, schedule scalars and row indices ride in SMEM
+(scalar prefetch).  The launch's scoped-VMEM limit is sized from its own
+operands.
 """
 
 from __future__ import annotations
@@ -62,14 +59,23 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flow_update.kernel import LANE, _flow_phase
+from repro.kernels.flow_update.kernel import (
+    LANE,
+    block,
+    compiler_params,
+    flow_phase,
+    walk,
+)
 
 READOUT_MODES = ("all", "hist", "raw")
 SUFFIX_KINDS = ("mlp", "mat", "centroid")
 
 # verdict sentinel for a dropped packet (flowstate.mitigation.MITIGATED)
 _MITIGATED = -1
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class TablePlan(NamedTuple):
@@ -104,7 +110,7 @@ class MitPlan(NamedTuple):
     attack_class: int
     drop: bool                 # mode == "drop" (else rate_limit)
     shared_seg: bool = False   # action slots == flow slots: reuse the
-                               # flow table's segmentation operands
+                               # flow table's schedule operands
 
 
 class Plan(NamedTuple):
@@ -117,21 +123,31 @@ class Plan(NamedTuple):
 
 # operand count per suffix kind (see the layout walked by _serve_kernel)
 N_SUFFIX_OPS = {"mlp": 2, "mat": 3, "centroid": 2}
+# VMEM operands per flow table: init, add, val, rank, fresh
+N_TABLE_OPS = 5
+
+
+def n_smem_ops(plan: Plan) -> int:
+    """Scalar-prefetch (SMEM) operand count: (sched, drain) per table, a
+    row permutation into the first table's order per further table, and
+    the action table's own (sched, drain) unless it shares table 0's."""
+    nt = len(plan.tables)
+    n = 2 * nt + (nt - 1)
+    if plan.mit is not None and not plan.mit.shared_seg:
+        n += 2
+    return n
 
 
 def n_mit_ops(mp: MitPlan) -> int:
-    """Mitigation block operand count.  The shared-segmentation fast path
-    ships only the table pair (mit_keys, mit_regs); the general form adds
-    its own segmentation + the verdict-order gather: pk, valid, rank,
-    seg_slot, from_v."""
-    return 2 if mp.shared_seg else 7
+    """Mitigation VMEM operand count: the chain heads' (hits, since) and
+    the eviction column; without a shared schedule also its own rank
+    column and ``from_v``, the verdict-order permutation."""
+    return 3 if mp.shared_seg else 5
 
 
 # ------------------------------------------------------- suffix evaluation
 #
-# Plain-jnp, shared bit-for-bit by the kernel body and the wrapper's
-# reference fallback (ops.py) — the over-envelope fallback is then a
-# pure schedule choice.
+# Plain jnp, run on the kernel's VMEM values.
 
 
 def suffix_readout(feats, tp: TablePlan):
@@ -160,7 +176,7 @@ def _label_rewrite(ids, lmap):
     onehot = (k_iota == ids[:, None]).astype(jnp.float32)
     return jnp.dot(
         onehot, lmap[0].astype(jnp.float32)[:, None],
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=_HIGHEST,
     )[:, 0].astype(jnp.int32)
 
 
@@ -192,8 +208,8 @@ def suffix_verdicts(z, arrays: tuple, sp: SuffixPlan):
         per-centroid squared distances (zero pad lanes add exact zeros),
         +inf-masked arg-reduce, LabelMap rewrite.
 
-    Rows that are all zero (ragged padding / sentinels) classify to some
-    fixed class — the engine slices those verdicts off."""
+    Rows that are all zero (ragged padding) classify to some fixed class
+    — the engine slices those verdicts off."""
     z = z.astype(jnp.float32)
     n_pkt = z.shape[0]
     if sp.kind == "mlp":
@@ -201,7 +217,8 @@ def suffix_verdicts(z, arrays: tuple, sp: SuffixPlan):
         h = jnp.pad(z, ((0, 0), (0, sp.lane - z.shape[1])))
         for l in range(sp.n_layers):     # static unroll: whole DNN in-kernel
             w = w_stack[l].astype(jnp.float32)
-            h = jnp.dot(h, w, preferred_element_type=jnp.float32)
+            h = jnp.dot(h, w, preferred_element_type=jnp.float32,
+                        precision=_HIGHEST)
             h = h + b_stack[l][None, :]
             if l < sp.n_layers - 1:
                 h = jnp.maximum(h, 0.0)
@@ -221,7 +238,7 @@ def suffix_verdicts(z, arrays: tuple, sp: SuffixPlan):
             onehot = (bin_iota == bucket[:, None]).astype(jnp.float32)
             scores = scores + jnp.dot(
                 onehot, tables[f].astype(jnp.float32),
-                preferred_element_type=jnp.float32,
+                preferred_element_type=jnp.float32, precision=_HIGHEST,
             )
         ids = _arg_reduce(scores, sp.num_classes, sp.use_min)
         return _label_rewrite(ids, lmap)
@@ -247,89 +264,57 @@ def suffix_verdicts(z, arrays: tuple, sp: SuffixPlan):
 # ------------------------------------------------------- mitigation phase
 
 
-def _mitigation_phase(mkeys, mregs, pk, vd, valid, rank, seg_slot,
-                      mp: MitPlan):
-    """The action-table update as ONE loop-free vectorized pass.
+def mitigation_phase(sched_ref, drain_ref, rank, init_h, init_s, fresh_ref,
+                     hits_ref, since_ref, out_ref, mp: MitPlan):
+    """The action-table update, on the flow tables' ``walk`` schedule.
 
-    The arrival-order scan of ``flowstate.mitigation.mitigate_update``
-    factorizes over maximal same-key RUNS of each slot chain (a mid-chain
-    key change is an evict-on-collision reset — a fresh row, exactly a
-    run head).  Within a run the state admits a closed form:
+    Operands are in the action table's sorted order: ``rank`` [B, 1] is
+    each packet's rank in its action-slot chain, ``init_h``/``init_s``
+    the stored [hits, since] of its slot (read by chain heads),
+    ``fresh_ref`` whether it evicts.  ``out_ref`` enters holding each
+    packet's classifier verdict and leaves holding the final verdict: a
+    packet's own row is rewritten only in the step that applies it, so
+    the step still reads the classifier's verdict there.  ``hits_ref`` /
+    ``since_ref`` leave holding each packet's post-update row.
 
-      * ``hits`` before packet i is the run head's carry-in plus the
-        prefix count of attack verdicts — a segmented cumsum;
-      * ``marked`` (``hits >= threshold``) is therefore MONOTONE within
-        the run, so the consecutive-marked streak feeding ``since`` is
-        just the count of marked predecessors in the run (plus the
-        head's carry-in when the head itself is marked).
-
-    Every quantity equals the sequential scan's value as an
-    integer-valued f32 (exact below 2**24, the same bound as the
-    LabelMap one-hot matvec), so verdicts and final state are
-    bit-identical to the reference — with no lockstep rounds and no
-    drain, just cumsums, gathers and two scatters.
-
-    mkeys [Sm] i32; mregs [Sm, Wt] f32 (columns 0/1 live, rest zero
-    padding); batch operands are [B_pad]-sized and SORTED by MITIGATION
-    slot (stable, so per-slot arrival order is preserved) with trailing
-    sentinels (``valid == 0``).  ``vd`` carries each packet's classifier
-    verdict in the same sorted order; ``seg_slot`` holds each segment's
-    slot at its segment-id row (the ``segment_batch`` convention).
-
-    -> (mkeys' [Sm], mregs' [Sm, Wt], out_verdicts [B_pad] sorted order;
-    untouched rows pass their classifier verdict through)."""
-    Sm, Wt = mregs.shape
-    B = pk.shape[0]
-    live = valid != 0
+    The step is ``flowstate.mitigation.mitigate_update``'s per-packet
+    arithmetic — the state BEFORE a packet decides its fate."""
     thr = jnp.float32(mp.threshold)
-    keep = jnp.float32(mp.keep_every)
-    atk = jnp.int32(mp.attack_class)
-    pos = jnp.arange(B, dtype=jnp.int32)
 
-    is_head = live & (rank == 0)                 # chain heads
-    seg_id = jnp.cumsum(is_head.astype(jnp.int32)) - 1
-    slot = seg_slot[jnp.maximum(seg_id, 0)]      # slot per sorted row
+    def step(prev, rows):
+        fresh = fresh_ref[rows, :] != 0
+        v = out_ref[rows, :]
+        h0 = jnp.where(fresh, 0.0, prev[0])
+        s0 = jnp.where(fresh, 0.0, prev[1])
+        marked = h0 >= thr
+        if mp.drop:
+            drop = marked
+        else:
+            # pass every keep_every-th packet of a marked flow through
+            # (since is an integer-valued f32 below 2**24: the int
+            # remainder equals the f32 mod exactly)
+            drop = marked & (jax.lax.rem(s0.astype(jnp.int32),
+                                         jnp.int32(mp.keep_every)) != 0)
+        out = jnp.where(drop, jnp.int32(_MITIGATED), v)
+        h1 = h0 + (v == jnp.int32(mp.attack_class)).astype(jnp.float32)
+        s1 = jnp.where(marked, s0 + 1.0, 0.0)
+        return [h1, s1], [out]
 
-    prev_pk = jnp.concatenate([pk[:1], pk[:-1]])
-    run_head = live & (is_head | (pk != prev_pk))
-    hidx = jax.lax.cummax(jnp.where(run_head, pos, 0))
+    hits_ref[...] = jnp.zeros_like(hits_ref)
+    since_ref[...] = jnp.zeros_like(since_ref)
+    walk(sched_ref, drain_ref, rank, [hits_ref, since_ref],
+         [init_h, init_s], step, [out_ref])
 
-    # table state carries in at CHAIN heads only; a key mismatch there —
-    # and every mid-chain run head — is a fresh (evicted) row
-    carry = is_head & (mkeys[slot] == pk)
-    h0 = jnp.where(carry, mregs[slot, 0], 0.0)
-    s0 = jnp.where(carry, mregs[slot, 1], 0.0)
 
-    a = (vd == atk).astype(jnp.float32)          # attack indicator
-    ecs = jnp.cumsum(a) - a                      # exclusive prefix count
-    h_before = h0[hidx] + (ecs - ecs[hidx])      # hits BEFORE each packet
-    m = h_before >= thr                          # marked BEFORE each packet
-    mf = m.astype(jnp.float32)
-    ems = jnp.cumsum(mf) - mf
-    m_run = ems - ems[hidx]                      # marked predecessors in run
-    since_before = m_run + jnp.where(m[hidx], s0[hidx], 0.0)
-
-    # the state BEFORE a packet decides its fate (mitigation contract)
-    if mp.drop:
-        drop = m
-    else:
-        # pass every keep_every-th packet of a marked flow through
-        drop = m & (jnp.mod(since_before, keep) != 0.0)
-    out = jnp.where(live & drop, jnp.int32(_MITIGATED), vd)
-
-    # the last live packet of each chain writes the row home
-    nxt_rank = jnp.concatenate([rank[1:], jnp.zeros((1,), rank.dtype)])
-    nxt_live = jnp.concatenate([live[1:], jnp.zeros((1,), bool)])
-    tail = live & (~nxt_live | (nxt_rank == 0))
-    hits1 = h_before + a
-    since1 = jnp.where(m, since_before + 1.0, 0.0)
-    colw = jax.lax.broadcasted_iota(jnp.int32, (B, Wt), 1)
-    new = jnp.where(colw == 0, hits1[:, None],
-                    jnp.where(colw == 1, since1[:, None], 0.0))
-    tgt = jnp.where(tail, slot, Sm)
-    mkeys = mkeys.at[tgt].set(pk, mode="drop")
-    mregs = mregs.at[tgt].set(new, mode="drop")
-    return mkeys, mregs, out
+def _permute_verdicts(verd, from_v):
+    """verd [B, 1] int32 -> verd[from_v] via a one-hot matmul (exact for
+    small integers at HIGHEST precision)."""
+    n = verd.shape[0]
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+              == from_v).astype(jnp.float32)
+    return jnp.dot(onehot, verd.astype(jnp.float32),
+                   preferred_element_type=jnp.float32,
+                   precision=_HIGHEST).astype(jnp.int32)
 
 
 # ------------------------------------------------------------ kernel body
@@ -337,199 +322,107 @@ def _mitigation_phase(mkeys, mregs, pk, vd, valid, rank, seg_slot,
 
 def _serve_kernel(*refs, plan: Plan):
     """One launch: per-table flow phases, suffix classify, optional
-    mitigation phase.  ``refs`` = input refs (layout below) ++ output
-    refs.  Narrow int operands keep column 0 live only.
+    mitigation phase.
 
-    Input layout: per table 13 flow-phase operands (as
-    ``flow_update._kernel``); then, multi-table only, one arrival-gather
-    index per table (``inv``); then the suffix parameter arrays
-    (``N_SUFFIX_OPS[kind]`` of them); then, mitigated only, the
-    ``n_mit_ops(plan.mit)`` mitigation operands — just (mit_keys,
-    mit_regs) on the shared-segmentation fast path (the flow table's
-    operands are reused wholesale), else the table pair + own
-    segmentation + ``from_v``, the verdict-order gather."""
+    Operand layout (``refs`` = SMEM ++ VMEM inputs ++ outputs ++ scratch):
+    SMEM — (sched, drain) per table, then ``perm`` per further table (row
+    p of the first table's order is row ``perm[p]`` of this table's),
+    then the action table's (sched, drain) unless ``shared_seg``.  VMEM —
+    ``N_TABLE_OPS`` per table (``flow_update_padded``'s layout), the
+    suffix parameter arrays, then the ``n_mit_ops`` mitigation operands
+    (init_h, init_s, fresh[, rank, from_v]).  Outputs — post-update rows
+    per table (each in its own sorted order), then (hits, since) when
+    mitigated, then the verdict column (first-table order, or the action
+    table's order when mitigated).  Scratch — one gather buffer per
+    further table."""
     nt = len(plan.tables)
-    n_in = (13 * nt + (nt if nt > 1 else 0)
-            + N_SUFFIX_OPS[plan.suffix.kind]
-            + (n_mit_ops(plan.mit) if plan.mit is not None else 0))
-    ins, outs = refs[:n_in], refs[n_in:]
-
-    cur = 0
-    new_tabs = []
-    feats_list = []
-    t0_seg = None
-    for tp in plan.tables:
-        (kr, rr, pkr, ur, br, vr, rkr, sfr, slr, ssr,
-         dor, dsr, dcr) = ins[cur:cur + 13]
-        cur += 13
-        if t0_seg is None:
-            # retained for the mitigation shared-segmentation fast path
-            t0_seg = (pkr, vr, rkr, ssr)
-        k2, r2, feats = _flow_phase(
-            kr[...][:, 0], rr[...], pkr[...][:, 0], ur[...],
-            br[...][:, :max(tp.n_hists, 1)], vr[...][:, 0],
-            rkr[...][:, 0], sfr[...][:, 0], slr[...][:, 0],
-            ssr[...][:, 0], dor[...][:, 0], dsr[...][:, 0],
-            dcr[...][:, 0],
-            n_counters=tp.n_counters, n_ewma=tp.n_ewma, alpha=tp.alpha,
-        )
-        new_tabs.append((k2, r2))
-        feats_list.append(feats)
-
-    if nt > 1:
-        # gather every table's feature rows to ARRIVAL order and feed the
-        # shared suffix the concatenated readouts; verdicts come out in
-        # arrival order directly
-        invs = ins[cur:cur + nt]
-        cur += nt
-        zs = [
-            suffix_readout(feats_list[t][invs[t][...][:, 0]],
-                           plan.tables[t])
-            for t in range(nt)
-        ]
-        z = jnp.concatenate(zs, axis=1)
-    else:
-        z = suffix_readout(feats_list[0], plan.tables[0])
-
+    n_smem = n_smem_ops(plan)
     n_sfx = N_SUFFIX_OPS[plan.suffix.kind]
-    s_arrays = tuple(r[...] for r in ins[cur:cur + n_sfx])
+    n_mit = n_mit_ops(plan.mit) if plan.mit is not None else 0
+    n_vin = N_TABLE_OPS * nt + n_sfx + n_mit
+    n_out = nt + (2 if plan.mit is not None else 0) + 1
+    smem = refs[:n_smem]
+    vin = refs[n_smem:n_smem + n_vin]
+    outs = refs[n_smem + n_vin:n_smem + n_vin + n_out]
+    scratch = refs[n_smem + n_vin + n_out:]
+
+    for t, tp in enumerate(plan.tables):
+        flow_phase(smem[2 * t], smem[2 * t + 1],
+                   *vin[N_TABLE_OPS * t:N_TABLE_OPS * (t + 1)], outs[t],
+                   n_counters=tp.n_counters, n_ewma=tp.n_ewma,
+                   alpha=tp.alpha)
+
+    zs = [suffix_readout(outs[0][...], plan.tables[0])]
+    for t in range(1, nt):
+        # bring table t's rows into the first table's order: one
+        # scalar-indexed row copy per packet (bit-exact)
+        perm, src, dst = smem[2 * nt + t - 1], outs[t], scratch[t - 1]
+
+        def gather(p, carry, perm=perm, src=src, dst=dst):
+            dst[pl.ds(p, 1), :] = src[pl.ds(perm[p], 1), :]
+            return carry
+
+        jax.lax.fori_loop(0, dst.shape[0], gather, 0)
+        zs.append(suffix_readout(dst[...], plan.tables[t]))
+    z = jnp.concatenate(zs, axis=1) if nt > 1 else zs[0]
+
+    cur = N_TABLE_OPS * nt
+    s_arrays = tuple(r[...] for r in vin[cur:cur + n_sfx])
     cur += n_sfx
-    verd = suffix_verdicts(z, s_arrays, plan.suffix)
+    verd = suffix_verdicts(z, s_arrays, plan.suffix)[:, None]
 
-    if plan.mit is not None:
-        if plan.mit.shared_seg:
-            # action slots == flow slots: the detection segmentation IS
-            # the mitigation segmentation and the suffix's sorted order
-            # is already mitigation order — no gather, two operands
-            mkr, mrr = ins[cur:cur + 2]
-            pkr, vr, rkr, ssr = t0_seg
-            mk2, mr2, final = _mitigation_phase(
-                mkr[...][:, 0], mrr[...], pkr[...][:, 0], verd,
-                vr[...][:, 0], rkr[...][:, 0], ssr[...][:, 0],
-                plan.mit,
-            )
-        else:
-            (mkr, mrr, mpkr, mvr, mrkr, mssr,
-             mfvr) = ins[cur:cur + 7]
-            # verdicts permute from the suffix's order (sorted-by-
-            # detection-slot, or arrival for multi-table) into
-            # mitigation-sorted order
-            vd_m = verd[mfvr[...][:, 0]]
-            mk2, mr2, final = _mitigation_phase(
-                mkr[...][:, 0], mrr[...], mpkr[...][:, 0], vd_m,
-                mvr[...][:, 0], mrkr[...][:, 0], mssr[...][:, 0],
-                plan.mit,
-            )
+    vo = outs[-1]
+    if plan.mit is None:
+        vo[...] = verd
+        return
+    hits_o, since_o = outs[nt], outs[nt + 1]
+    mit_ops = vin[cur:cur + n_mit]
+    init_h, init_s, fresh_m = mit_ops[:3]
+    if plan.mit.shared_seg:
+        # action slots == flow slots: the flow schedule IS the action
+        # table's and the suffix's order is already its order
+        sched, drain = smem[0], smem[1]
+        rank = vin[3][...]
+        vo[...] = verd
     else:
-        final = verd
-
-    oc = 0
-    for k2, r2 in new_tabs:
-        ko, ro = outs[oc:oc + 2]
-        oc += 2
-        ko[...] = jnp.pad(k2[:, None], ((0, 0), (0, ko.shape[1] - 1)))
-        ro[...] = r2
-    if plan.mit is not None:
-        mko, mro = outs[oc:oc + 2]
-        oc += 2
-        mko[...] = jnp.pad(mk2[:, None], ((0, 0), (0, mko.shape[1] - 1)))
-        mro[...] = mr2
-    vo = outs[oc]
-    vo[...] = jnp.broadcast_to(final[:, None], vo.shape)
+        sched, drain = smem[-2], smem[-1]
+        rank = mit_ops[3][...]
+        vo[...] = _permute_verdicts(verd, mit_ops[4][...])
+    mitigation_phase(sched, drain, rank, init_h[...], init_s[...], fresh_m,
+                     hits_o, since_o, vo, plan.mit)
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "interpret"))
 def fused_flow_serve_padded(*ops, plan: Plan, interpret: bool = False):
-    """Padded/segmented operands (layout in ``_serve_kernel``) -> flat
-    outputs: per table (keys' [S, kw], regs' [S, w_pad]), then the
-    mitigated table pair when ``plan.mit``, then verdicts [B_pad, kw]
-    int32 (class id in column 0) in the suffix's order — SORTED for one
-    table, ARRIVAL for multi-table, MITIGATION-SORTED when mitigated."""
+    """Packed operands (layout in ``_serve_kernel``: SMEM operands first)
+    -> flat outputs: post-update rows [B_pad, W_pad] per table in its
+    sorted order, then (hits, since) [B_pad, 1] f32 when ``plan.mit``,
+    then verdicts [B_pad, 1] int32 — first-table sorted order, or the
+    action table's sorted order when mitigated."""
     nt = len(plan.tables)
-    tile = ops[0].shape[1]
-    b_pad = ops[2].shape[0]
+    n_smem = n_smem_ops(plan)
+    vin = ops[n_smem:]
+    b_pad = vin[0].shape[0]
 
-    def full(arr):
-        nd = arr.ndim
-        return pl.BlockSpec(arr.shape, lambda i, _n=nd: (0,) * _n)
-
-    out_specs, out_shape = [], []
-
-    def add_out(shape, dtype):
-        out_specs.append(pl.BlockSpec(shape, lambda i, _n=len(shape):
-                                      (0,) * _n))
-        out_shape.append(jax.ShapeDtypeStruct(shape, dtype))
-
-    for t in range(nt):
-        s_t = ops[13 * t].shape[0]
-        w_pad_t = ops[13 * t + 1].shape[1]
-        add_out((s_t, tile), jnp.int32)
-        add_out((s_t, w_pad_t), jnp.float32)
+    out_shape = [jax.ShapeDtypeStruct(vin[N_TABLE_OPS * t].shape,
+                                      jnp.float32) for t in range(nt)]
     if plan.mit is not None:
-        m_off = (13 * nt + (nt if nt > 1 else 0)
-                 + N_SUFFIX_OPS[plan.suffix.kind])
-        sm = ops[m_off].shape[0]
-        wt = ops[m_off + 1].shape[1]
-        add_out((sm, tile), jnp.int32)
-        add_out((sm, wt), jnp.float32)
-    add_out((b_pad, tile), jnp.int32)
-
+        out_shape += [jax.ShapeDtypeStruct((b_pad, 1), jnp.float32)] * 2
+    out_shape.append(jax.ShapeDtypeStruct((b_pad, 1), jnp.int32))
+    scratch = [pltpu.VMEM(vin[N_TABLE_OPS * t].shape, jnp.float32)
+               for t in range(1, nt)]
     return pl.pallas_call(
         functools.partial(_serve_kernel, plan=plan),
-        grid=(1,),
-        in_specs=[full(a) for a in ops],
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_smem,
+            grid=(1,),
+            in_specs=[block(a.shape) for a in vin],
+            out_specs=[block(s.shape) for s in out_shape],
+            scratch_shapes=scratch,
+        ),
         out_shape=out_shape,
+        compiler_params=compiler_params(
+            sum(a.size * a.dtype.itemsize for a in vin) * 2
+            + sum(s.size * 4 for s in out_shape) * 2 + b_pad * b_pad * 4),
         interpret=interpret,
     )(*ops)
-
-
-def fused_flow_classify_padded(
-    keys, regs, pkt_keys, upd, bins, valid, rank, seg_first, seg_len,
-    seg_slot, drain_order, drain_sid, deep_src, w_stack, b_stack, *,
-    n_counters: int, n_ewma: int, n_hists: int, alpha: float, head: int,
-    mode: str, width: int, n_layers: int, num_classes: int, lane: int,
-    interpret: bool = False,
-):
-    """The PR-6 single-table MLP form, kept as a thin wrapper over the
-    ``Plan``-driven launcher: -> (keys' [S, kw], regs' [S, w_pad],
-    verdicts [B_pad, kw] int32 in SORTED order, class id in column 0)."""
-    del head
-    plan = Plan(
-        tables=(TablePlan(n_counters, n_ewma, n_hists, alpha, width, mode),),
-        suffix=SuffixPlan("mlp", num_classes, n_layers=n_layers, lane=lane),
-    )
-    return fused_flow_serve_padded(
-        keys, regs, pkt_keys, upd, bins, valid, rank, seg_first, seg_len,
-        seg_slot, drain_order, drain_sid, deep_src, w_stack, b_stack,
-        plan=plan, interpret=interpret,
-    )
-
-
-def vmem_bytes(n_slots: int, width: int, n_layers: int, lane: int,
-               batch: int = 256, *, suffix: str = "mlp",
-               n_features: int = 0, n_bins: int = 0, num_classes: int = 0,
-               n_centroids: int = 0, extra_tables: tuple = (),
-               mit_slots: int = 0) -> int:
-    """Resident working set of the fused launch (feasibility input):
-    flow-update set(s) plus the suffix parameters, one activation tile,
-    and — when mitigation is folded in — the action table with its own
-    scheduling operands.  ``extra_tables`` lists additional flow tables
-    as (n_slots, width) pairs for the multi-table form."""
-    from repro.kernels.flow_update.kernel import vmem_bytes as flow_bytes
-
-    total = flow_bytes(n_slots, width, batch)
-    for s2, w2 in extra_tables:
-        total += flow_bytes(s2, w2, batch)
-    if suffix == "mlp":
-        total += n_layers * (lane * lane + lane) * 4 + 2 * batch * lane * 4
-    elif suffix == "mat":
-        total += n_features * (n_bins * max(num_classes, 1) + n_bins) * 4
-        total += 2 * batch * max(n_features, num_classes, 1) * 4
-    elif suffix == "centroid":
-        total += n_centroids * max(lane, 1) * 4 + 2 * batch * lane * 4
-    if mit_slots:
-        # [hits, since] + key per slot, plus (worst case, non-shared
-        # segmentation) the 7 per-batch mitigation operand columns
-        total += mit_slots * 3 * 4 + batch * 4 * 7
-    return total

@@ -4,13 +4,15 @@
 per flow table (the shared ``flow_update.segment_batch`` prelude) — plus
 once more over the action table's own slot space when mitigation is
 folded in with a slot count different from the flow table's (same count:
-the flow segmentation is reused wholesale, ``MitPlan.shared_seg``) —
-launches the ``Plan``-driven fused Pallas kernel (interpret=True on CPU)
-and restores the [B] int32 verdicts to arrival order.  This is the executable artifact
-``core.pallas_backend.lower_stateful_fused`` emits for a fused-eligible
-stateful pipeline — the backend string ``"pallas-fused-flow"`` means
-exactly this launch is serving.  ``fused_flow_classify`` keeps the PR-6
-single-table MLP signature as a thin wrapper.
+the flow schedule is reused wholesale, ``MitPlan.shared_seg``) — gathers
+each packet's slot rows, launches the ``Plan``-driven fused Pallas kernel
+(interpret=True on CPU), scatters every chain's last row back into its
+table and restores the [B] int32 verdicts to arrival order.  This is the
+executable artifact ``core.pallas_backend.lower_stateful_fused`` emits
+for a fused-eligible stateful pipeline — the backend string
+``"pallas-fused-flow"`` means exactly this launch is serving.
+``fused_flow_classify`` keeps the single-table MLP signature as a thin
+wrapper.
 
 Suffix parameters arrive PRE-PADDED (lane-snapped MLP stacks, +inf-padded
 MAT edges, zero-padded tables/centroids): packing happens once at
@@ -18,36 +20,31 @@ lowering time, not per batch.
 
 Bit-identity contract: state, features and verdicts equal the split
 composition (flow_update + WindowStats.apply + classifier [+
-mitigate_update]) bit for bit — the update phases are the shared
-``_flow_phase``/``_mitigation_phase`` schedules and the classifier phase
-shares ``suffix_readout``/``suffix_verdicts`` with the reference path
-(MAT parity quantization-bounded per the lowering contract).  The kernel
-serves every in-envelope batch — the doubly-compacted drain walks deep
-chains at a small fixed per-packet cost, measured well under the
-reference walk even on a fully-degenerate single-chain batch, so there
-is no drain-routing ``lax.cond`` (``telemetry.flow_health`` still flags
-drain-heavy batches as a traffic-shape signal).  Outside the kernel
-envelope (table over VMEM bounds, B == 0) the op falls back to the jnp
-scan reference + the same suffix evaluation; every path computes
-identical bits.
+mitigate_update]) bit for bit — the update phases run the reference's
+per-packet arithmetic on the shared ``walk`` schedule (MAT parity
+quantization-bounded per the lowering contract).  Tables outside the
+kernel envelope raise: the lowering declines them with a reason first,
+so there is no silent reference path here.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.flow_update.ops import (
-    MAX_HISTS,
-    MAX_SLOTS,
-    MAX_WIDTH,
     _snap,
-    pack_segmented_operands,
+    chain_fresh,
+    col,
+    envelope_reason,
+    pack_flow_operands,
+    padded_rows,
+    rows,
+    scatter_tails,
     segment_batch,
+    smem_vec,
 )
-from repro.kernels.flow_update.ref import flow_update_ref, hash_slot
+from repro.kernels.flow_update.ref import hash_slot
 from repro.kernels.fused_flow.kernel import (
     LANE,
     MitPlan,
@@ -55,49 +52,11 @@ from repro.kernels.fused_flow.kernel import (
     SuffixPlan,
     TablePlan,
     fused_flow_serve_padded,
-    suffix_readout,
-    suffix_verdicts,
 )
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
-
-
-def _icol(vals, b: int, tile: int, fill: int = 0):
-    """[b] int values -> [b + tile, tile] int32 with column 0 live and
-    ``fill`` everywhere else (the narrow-operand sentinel convention)."""
-    out = jnp.full((b + tile, tile), fill, jnp.int32)
-    return out.at[:b, 0].set(vals)
-
-
-def _pack_mit_table(mit_keys, mit_regs, *, tile: int):
-    """Pad the action table pair to kernel tile shapes."""
-    Sm = mit_keys.shape[0]
-    return (
-        jnp.zeros((Sm, tile), jnp.int32).at[:, 0].set(mit_keys),
-        jnp.pad(mit_regs, ((0, 0), (0, tile - mit_regs.shape[1]))),
-    )
-
-
-def _pack_mitigation_operands(mseg, mit_keys, mit_regs, pkt_keys, valid,
-                              from_v, *, tile: int):
-    """Permute the batch into MITIGATION-slot-sorted order and pad the
-    action table + its segmentation to kernel tile shapes (the loop-free
-    closed-form phase needs only rank + seg_slot, no lockstep/drain
-    bookkeeping).  ``from_v[i]`` maps the packet at mitigation-sorted
-    position i to its row in the suffix's verdict array; sentinels point
-    at a sentinel verdict row."""
-    B = pkt_keys.shape[0]
-    o = mseg.order
-    icol = functools.partial(_icol, b=B, tile=tile)
-    return _pack_mit_table(mit_keys, mit_regs, tile=tile) + (
-        icol(pkt_keys[o]),
-        icol(valid[o]),
-        icol(mseg.rank),
-        icol(mseg.seg_slot),
-        icol(from_v, fill=B),
-    )
 
 
 def fused_flow_serve(
@@ -118,7 +77,8 @@ def fused_flow_serve(
     Rows with ``valid == 0`` never touch any table and keep meaningless
     verdicts (the engine slices them off).  Bit-identical to the split
     composition; see the flow-state and mitigation contracts in
-    docs/pipeline_ir.md."""
+    docs/pipeline_ir.md.  Raises ``ValueError`` for a table outside the
+    kernel envelope."""
     if interpret is None:
         interpret = not _on_tpu()
     tables = [
@@ -133,109 +93,85 @@ def fused_flow_serve(
     nt = len(tables)
     B = int(tables[0][2].shape[0])
 
+    for (k, r, _, _, _), tp in zip(tables, table_plans):
+        reason = envelope_reason(int(k.shape[0]), int(r.shape[1]),
+                                 tp.n_hists)
+        if reason:
+            raise ValueError(reason)
+    mit_plan = None
     if mitigation is not None:
-        from repro.flowstate.mitigation import mitigate_update
-
         mit_keys = jnp.asarray(mitigation[0], jnp.int32)
         mit_regs = jnp.asarray(mitigation[1], jnp.float32)
         mspec = mitigation[2]
+        reason = envelope_reason(int(mit_keys.shape[0]), 2, 0)
+        if reason:
+            raise ValueError("mitigation: " + reason)
         # same slot count as a single flow table -> hash(key) & (S-1)
-        # gives identical slots, so the flow segmentation is reused
-        # wholesale (no second sort, no verdict permutation)
-        shared = (len(tables) == 1
+        # gives identical slots, so the flow schedule is reused wholesale
+        shared = (nt == 1
                   and int(mit_keys.shape[0]) == int(tables[0][0].shape[0]))
         mit_plan = MitPlan(mspec.threshold, mspec.keep_every,
                            mspec.attack_class, mspec.mode == "drop",
                            shared_seg=shared)
-    else:
-        mit_plan = None
-
-    def reference_full():
-        outs = []
-        zs = []
-        for (k, r, pk, u, b), tp in zip(tables, table_plans):
-            k2, r2, feats = flow_update_ref(
-                k, r, pk, u, b, valid,
-                n_counters=tp.n_counters, n_ewma=tp.n_ewma, alpha=tp.alpha,
-            )
-            outs += [k2, r2]
-            zs.append(suffix_readout(feats, tp))
-        z = jnp.concatenate(zs, 1) if nt > 1 else zs[0]
-        verd = suffix_verdicts(z, suffix_arrays, suffix_plan)
+    if B == 0:
+        outs = [a for (k, r, _, _, _) in tables for a in (k, r)]
         if mit_plan is not None:
-            mk2, mr2, verd = mitigate_update(
-                mit_keys, mit_regs, tables[0][2], verd, valid, spec=mspec)
-            outs += [mk2, mr2]
-        return tuple(outs) + (verd,)
-
-    over = any(
-        int(r.shape[0]) > MAX_SLOTS or int(r.shape[1]) > MAX_WIDTH
-        or int(b.shape[1] if b.ndim == 2 else 0) > MAX_HISTS
-        for (_, r, _, _, b) in tables
-    )
-    if mit_plan is not None and int(mit_keys.shape[0]) > MAX_SLOTS:
-        over = True
-    if over or B == 0:
-        return reference_full()
+            outs += [mit_keys, mit_regs]
+        return tuple(outs) + (jnp.zeros((0,), jnp.int32),)
 
     # CPU interpret mode snaps pads to 8-wide tiles; TPU pads the last
     # dim to the full 128 lane.
     tile = 8 if interpret else LANE
+    b_pad = padded_rows(B)
     segs = [
         segment_batch(hash_slot(pk, int(k.shape[0])), valid,
                       int(k.shape[0]))
         for (k, _, pk, _, _) in tables
     ]
+    smem, vmem = [], []
+    for (k, r, pk, u, b), tp, seg in zip(tables, table_plans, segs):
+        sched, drain, *blocks = pack_flow_operands(
+            seg, k, r, pk, u, b, n_counters=tp.n_counters,
+            n_ewma=tp.n_ewma, w_pad=_snap(int(r.shape[1]), tile))
+        smem += [sched, drain]
+        vmem += blocks
+    seg0 = segs[0]
+    # the suffix runs in the first table's sorted order: row p there is
+    # row inv_t[order_0[p]] of table t's
+    smem += [smem_vec(seg.inv[seg0.order], b_pad) for seg in segs[1:]]
+    vmem += list(suffix_arrays)
+
+    pk0 = tables[0][2]
     if mit_plan is not None:
-        mseg = (segs[0] if mit_plan.shared_seg else segment_batch(
-            hash_slot(tables[0][2], int(mit_keys.shape[0])), valid,
-            int(mit_keys.shape[0])))
+        mseg = seg0 if mit_plan.shared_seg else segment_batch(
+            hash_slot(pk0, int(mit_keys.shape[0])), valid,
+            int(mit_keys.shape[0]))
+        head = mit_regs[mseg.slot]
+        vmem += [
+            rows(head[:, :1], b_pad, 1),
+            rows(head[:, 1:2], b_pad, 1),
+            col(chain_fresh(mseg, pk0[mseg.order], mit_keys[mseg.slot]),
+                b_pad),
+        ]
+        if not mit_plan.shared_seg:
+            smem += [mseg.sched, smem_vec(mseg.drain, b_pad)]
+            vmem += [col(mseg.rank, b_pad, fill=-1),
+                     col(seg0.inv[mseg.order], b_pad)]
+
     plan = Plan(tables=table_plans, suffix=suffix_plan, mit=mit_plan)
-
-    def launch():
-        flat = []
-        for (k, r, pk, u, b), tp, seg in zip(tables, table_plans, segs):
-            H = int(b.shape[1]) if b.ndim == 2 else 0
-            flat += list(pack_segmented_operands(
-                seg, k, r, pk, u, b, valid, tile=tile,
-                w_pad=_snap(int(r.shape[1]), tile),
-                u_pad=_snap(int(u.shape[1]), tile),
-                h_pad=_snap(H, tile) if not interpret else max(H, 1),
-            ))
-        if nt > 1:
-            # arrival-gather index per table: suffix rows re-assemble in
-            # arrival order inside the kernel
-            flat += [_icol(seg.inv, B, tile, fill=B) for seg in segs]
-        flat += list(suffix_arrays)
-        if mit_plan is not None:
-            if mit_plan.shared_seg:
-                flat += list(_pack_mit_table(mit_keys, mit_regs,
-                                             tile=tile))
-            else:
-                from_v = (mseg.order if nt > 1
-                          else segs[0].inv[mseg.order])
-                flat += list(_pack_mitigation_operands(
-                    mseg, mit_keys, mit_regs, tables[0][2], valid,
-                    from_v, tile=tile,
-                ))
-        res = fused_flow_serve_padded(*flat, plan=plan,
-                                      interpret=interpret)
-        outs = []
-        i = 0
-        for (_, r, _, _, _) in tables:
-            outs += [res[i][:, 0], res[i + 1][:, :int(r.shape[1])]]
-            i += 2
-        if mit_plan is not None:
-            outs += [res[i][:, 0], res[i + 1][:, :mit_regs.shape[1]]]
-            i += 2
-        verd = res[i][:B, 0]
-        if mit_plan is not None:
-            verd = verd[mseg.inv]        # mitigation-sorted -> arrival
-        elif nt == 1:
-            verd = verd[segs[0].inv]     # detection-sorted -> arrival
-        return tuple(outs) + (verd,)
-
-    return launch()
+    res = fused_flow_serve_padded(*smem, *vmem, plan=plan,
+                                  interpret=interpret)
+    outs = []
+    for t, ((k, r, pk, _, _), seg) in enumerate(zip(tables, segs)):
+        outs += scatter_tails(seg, k, r, pk, res[t][:B, :int(r.shape[1])])
+    verd = res[-1][:B, 0]
+    if mit_plan is not None:
+        post = jnp.concatenate([res[nt][:B], res[nt + 1][:B]], axis=1)
+        outs += scatter_tails(mseg, mit_keys, mit_regs, pk0, post)
+        verd = verd[mseg.inv]          # action-table order -> arrival
+    else:
+        verd = verd[seg0.inv]          # first-table order -> arrival
+    return tuple(outs) + (verd,)
 
 
 def fused_flow_classify(
@@ -256,8 +192,8 @@ def fused_flow_classify(
     lane: int,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """The PR-6 single-table MLP form -> (keys' [S], regs' [S, W],
-    verdicts [B] int32 in arrival order), one kernel launch."""
+    """The single-table MLP form -> (keys' [S], regs' [S, W], verdicts
+    [B] int32 in arrival order), one kernel launch."""
     H = int(bins.shape[1]) if bins.ndim == 2 else 0
     tp = TablePlan(n_counters, n_ewma, H, float(alpha),
                    int(regs.shape[1]), mode)

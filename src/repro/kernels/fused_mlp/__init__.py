@@ -1,4 +1,5 @@
 from repro.kernels.fused_mlp.ops import (
+    envelope_reason,
     fused_dag,
     fused_dag_reference,
     fused_mlp,

@@ -61,7 +61,8 @@ def _kernel(x_ref, w_ref, b_ref, o_ref, *, n_layers: int):
     h = x_ref[...].astype(jnp.float32)
     for l in range(n_layers):  # static unroll: the whole DNN in one launch
         w = w_ref[l].astype(jnp.float32)
-        h = jnp.dot(h, w, preferred_element_type=jnp.float32)
+        h = jnp.dot(h, w, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
         h = h + b_ref[l][None, :]
         if l < n_layers - 1:
             h = jnp.maximum(h, 0.0)
@@ -76,7 +77,8 @@ def _classify_kernel(x_ref, w_ref, b_ref, o_ref, *, n_layers: int,
     h = x_ref[...].astype(jnp.float32)
     for l in range(n_layers):
         w = w_ref[l].astype(jnp.float32)
-        h = jnp.dot(h, w, preferred_element_type=jnp.float32)
+        h = jnp.dot(h, w, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
         h = h + b_ref[l][None, :]
         if l < n_layers - 1:
             h = jnp.maximum(h, 0.0)
@@ -170,7 +172,8 @@ def _dag_kernel(x_ref, *refs, n_layers: tuple, n_classes: tuple,
         h = h0[:, :lanes[i]]
         for l in range(n_l):
             w = w_ref[l].astype(jnp.float32)
-            h = jnp.dot(h, w, preferred_element_type=jnp.float32)
+            h = jnp.dot(h, w, preferred_element_type=jnp.float32,
+                        precision=jax.lax.Precision.HIGHEST)
             h = h + b_ref[l][None, :]
             if l < n_l - 1:
                 h = jnp.maximum(h, 0.0)

@@ -32,18 +32,26 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _prepare(x, weights, interpret, block_b, lane):
-    """Shared kernel preamble for both entry points.
+def envelope_reason(n_in: int, weights, lane: int = LANE) -> str | None:
+    """Why an MLP is outside the fused kernel's envelope (None = inside):
+    every layer must fit one ``lane``-wide tile."""
+    widths = [int(n_in)] + [int(w.shape[1]) for w in weights]
+    if max(widths) > lane:
+        return f"layer width {max(widths)} exceeds the {lane}-lane tile"
+    return None
 
-    -> None when the model is outside the fused kernel's envelope (wide
-    layers -> XLA reference path), else (x_pad, block_b, interpret, lane)."""
+
+def _prepare(x, weights, interpret, block_b, lane):
+    """Shared kernel preamble for both entry points -> (x_pad, block_b,
+    interpret, lane); raises ``ValueError`` outside the envelope."""
     if interpret is None:
         interpret = not _on_tpu()
     if lane is None:
         lane = LANE
     B, F = x.shape
-    if F > lane or any(w.shape[1] > lane for w in weights):
-        return None
+    reason = envelope_reason(F, weights, lane)
+    if reason:
+        raise ValueError(reason)
     block_b = min(block_b, max(8, B))
     pad_b = (-B) % block_b
     x_pad = pad_to_lane(jnp.pad(x, ((0, pad_b), (0, 0))), 1, lane)
@@ -65,10 +73,8 @@ def fused_mlp(
     the Pallas serving backend passes ``kernel.snap_lane`` so CPU interpret
     mode runs model-sized tiles.  Numerics are lane-independent: pad lanes
     are exact zeros."""
-    prep = _prepare(x, weights, interpret, block_b, lane)
-    if prep is None:
-        return mlp_ref(x, weights, biases)
-    x_pad, block_b, interpret, lane = prep
+    x_pad, block_b, interpret, lane = _prepare(x, weights, interpret,
+                                               block_b, lane)
     w_stack, b_stack = pack_params(weights, biases, lane)
     out = fused_mlp_padded(
         x_pad, w_stack, b_stack,
@@ -89,10 +95,8 @@ def fused_mlp_classify(
     """x: [B, F] -> class ids [B] int32, argmax fused into the kernel.
 
     Same ``lane`` contract as :func:`fused_mlp`."""
-    prep = _prepare(x, weights, interpret, block_b, lane)
-    if prep is None:
-        return jnp.argmax(mlp_ref(x, weights, biases), -1).astype(jnp.int32)
-    x_pad, block_b, interpret, lane = prep
+    x_pad, block_b, interpret, lane = _prepare(x, weights, interpret,
+                                               block_b, lane)
     w_stack, b_stack = pack_params(weights, biases, lane)
     out = fused_mlp_classify_padded(
         x_pad, w_stack, b_stack,

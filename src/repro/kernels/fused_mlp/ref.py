@@ -21,7 +21,9 @@ def mlp_ref(x: jax.Array, weights: list[jax.Array], biases: list[jax.Array]
     h = x.astype(jnp.float32)
     L = len(weights)
     for i, (w, b) in enumerate(zip(weights, biases)):
-        h = h @ w.astype(jnp.float32) + b.astype(jnp.float32)
+        h = jnp.dot(h, w.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST) + b.astype(
+                        jnp.float32)
         if i < L - 1:
             h = jax.nn.relu(h)
     return h.astype(x.dtype)

@@ -1,4 +1,5 @@
 from repro.kernels.mat_lut.ops import (
+    envelope_reason,
     mat_classify,
     mat_classify_reference,
     MAX_BINS,

@@ -56,6 +56,7 @@ def _kernel(x_ref, edges_ref, tables_ref, lmap_ref, o_ref, *,
         scores = scores + jnp.dot(
             onehot, tables_ref[f].astype(jnp.float32),
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
     lane = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     if use_min:
@@ -71,6 +72,7 @@ def _kernel(x_ref, edges_ref, tables_ref, lmap_ref, o_ref, *,
     verdict = jnp.dot(
         onehot_k, lmap_ref[0].astype(jnp.float32)[:, None],
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )[:, 0].astype(jnp.int32)
     o_ref[...] = jnp.broadcast_to(verdict[:, None], o_ref.shape)
 

@@ -33,6 +33,21 @@ def _snap(n: int, tile: int) -> int:
     return max(tile, -(-n // tile) * tile)
 
 
+def envelope_reason(tables, label_map) -> str | None:
+    """Why a MAT pipeline is outside the kernel's envelope (None =
+    inside): too many features/bins for resident VMEM tables, or more
+    classes/labels than one lane tile."""
+    F, bins, C = (int(n) for n in tables.shape)
+    K = int(label_map.shape[0])
+    if F > MAX_FEATURES:
+        return f"{F} features exceed MAX_FEATURES={MAX_FEATURES}"
+    if bins > MAX_BINS:
+        return f"{bins} bins exceed MAX_BINS={MAX_BINS}"
+    if max(C, K) > LANE:
+        return f"{max(C, K)} classes exceed the {LANE}-lane tile"
+    return None
+
+
 def mat_classify(
     x: jax.Array,          # [B, F] f32
     edges: jax.Array,      # [F, BINS-1]
@@ -45,17 +60,16 @@ def mat_classify(
 ) -> jax.Array:
     """x: [B, F] -> verdicts [B] int32, the whole MAT pipeline fused.
 
-    Falls back to the jnp reference when the tables are outside the kernel
-    envelope (too many features/bins/classes for resident VMEM tables)."""
+    Raises ``ValueError`` outside the kernel envelope (the lowering
+    declines such tables first, so there is no silent reference path)."""
     if interpret is None:
         interpret = not _on_tpu()
+    reason = envelope_reason(tables, label_map)
+    if reason:
+        raise ValueError(reason)
     B, F = x.shape
     bins, C = tables.shape[1], tables.shape[2]
     K = label_map.shape[0]
-    if F > MAX_FEATURES or bins > MAX_BINS or C > LANE or K > LANE:
-        return mat_pipeline_ref(
-            x, edges, tables, label_map, use_min=use_min
-        ).astype(jnp.int32)
     # CPU interpret mode snaps pads to 8-wide tiles; TPU pads last dims to
     # the full 128 lane (second-to-last / leading dims only need sublanes)
     tile = 8 if interpret else LANE

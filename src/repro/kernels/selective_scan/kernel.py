@@ -56,11 +56,7 @@ def _scan_kernel(
         h = dA_t * h + dBx_t
         c_t = c_ref[0, t]        # [N]
         y_t = jnp.sum(h * c_t[None, :], axis=-1)  # [di]
-        pl.store(
-            y_ref,
-            (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-            y_t[None, None, :],
-        )
+        y_ref[pl.ds(0, 1), pl.ds(t, 1), :] = y_t[None, None, :]
         return h
 
     h = jax.lax.fori_loop(0, chunk, step, h_scr[...])

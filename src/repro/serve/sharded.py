@@ -427,8 +427,6 @@ def _build_sharded_step(traceable, devices, *, n_state: int):
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro import _compat  # noqa: F401  (jax.shard_map polyfill)
-
     mesh = Mesh(np.array(devices), ("data",))
 
     if n_state:
@@ -442,13 +440,13 @@ def _build_sharded_step(traceable, devices, *, n_state: int):
             step, mesh=mesh,
             in_specs=(P("data"),) * (n_state + 2),
             out_specs=(P("data"),) * (n_state + 1),
-            check_rep=False,
+            check_vma=False,
         )
         return mesh, jax.jit(fn)
 
     fn = jax.shard_map(lambda x: traceable(x), mesh=mesh,
                        in_specs=(P("data"),), out_specs=P("data"),
-                       check_rep=False)
+                       check_vma=False)
     jitted = jax.jit(fn)
 
     def dispatch(buf):

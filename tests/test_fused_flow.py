@@ -187,6 +187,32 @@ def test_fused_fallback_stays_honest(rng):
     np.testing.assert_array_equal(vi, vp)
 
 
+@needs_pallas
+def test_table_beyond_envelope_declines_with_reason():
+    """A flow table past ``MAX_SLOTS`` is declined at lowering with the
+    envelope's reason, and feasibility charges the same refusal: it is
+    never handed to the kernel compiler, and the split path it falls to
+    reports the reference flow update it runs."""
+    from repro.core import feasibility
+    from repro.data import traffic
+    from repro.kernels.flow_update import MAX_SLOTS
+
+    (fk, ru, ws), _ = traffic.flow_feature_stages(n_slots=2 * MAX_SLOTS)
+    r = np.random.default_rng(0)
+    mlp = stageir.FusedMLP(
+        [r.normal(size=(ws.n_out, 8)).astype(np.float32),
+         r.normal(size=(8, 2)).astype(np.float32)],
+        [np.zeros(8, np.float32), np.zeros(2, np.float32)])
+    pp = StatefulPipeline([fk, ru, ws, mlp, stageir.Reduce("argmax")],
+                          backend="pallas")
+    assert not pp.fused and pp.flow_backend == "interpret"
+    assert pp.backend == "mixed"
+    assert "MAX_SLOTS" in pp.fallback_reason
+    rep = feasibility.flowstate_report(ru.spec, "tpu")
+    assert not rep.feasible
+    assert any("MAX_SLOTS" in reason for reason in rep.reasons)
+
+
 def stages_out(spec):
     """WindowStats(mode='all') output width for ``spec``."""
     return stageir.WindowStats(spec, mode="all").n_out

@@ -59,13 +59,20 @@ def test_fused_mlp_matches_oracle(f, c, b, depth, dtype, data):
 
 
 def test_fused_mlp_wide_fallback():
-    """Widths beyond the 128-lane envelope fall back to the XLA reference."""
+    """Widths beyond the 128-lane envelope: the kernel op refuses with the
+    reason, and the interpreter's stage walk falls back to the XLA
+    reference."""
+    from repro.core.stageir import FusedMLP
+
     rng = np.random.default_rng(0)
     ws = [jnp.asarray(rng.normal(size=(200, 64)), jnp.float32) * 0.1,
           jnp.asarray(rng.normal(size=(64, 3)), jnp.float32) * 0.1]
     bs = [jnp.zeros((64,)), jnp.zeros((3,))]
     x = jnp.asarray(rng.normal(size=(17, 200)), jnp.float32)
-    out = fused_mlp(x, ws, bs)
+    with pytest.raises(ValueError, match="128-lane tile"):
+        fused_mlp(x, ws, bs)
+    out = FusedMLP([np.asarray(w) for w in ws],
+                   [np.asarray(b) for b in bs]).apply(x)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(mlp_ref(x, ws, bs)), rtol=1e-5, atol=1e-5
     )
