@@ -44,6 +44,8 @@ half runs on Pallas reports ``"mixed"``.
 
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import stageir
@@ -54,6 +56,7 @@ from repro.flowstate.registers import (
     init_state,
     migrate_state,
 )
+from repro.telemetry.trace import annotate
 
 
 class StatefulPipeline:
@@ -70,8 +73,6 @@ class StatefulPipeline:
                  backend: str = "interpret", fuse: bool = True):
         if backend not in stageir.EXEC_BACKENDS:
             raise KeyError(f"backend must be one of {stageir.EXEC_BACKENDS}")
-        import jax
-
         from repro.core import pallas_backend
 
         self.stages = list(stages)
@@ -135,8 +136,6 @@ class StatefulPipeline:
                 def suffix_fn(feats, _s=run_suffix):
                     return stageir.apply_stages(_s, feats)
 
-            import jax.numpy as jnp
-
             readouts = tuple(g[2] for g in groups)  # WindowStats | None
 
             def step(*args, _flows=tuple(f for f, _ in flows),
@@ -175,7 +174,10 @@ class StatefulPipeline:
                 self.mitigation_backend = None
 
         # the raw traceable step: what ShardedPacketServeEngine wraps in
-        # shard_map over per-device register tables
+        # shard_map over per-device register tables.  Its name is the
+        # step's stable name in a profile (``jit_flow_serve_step``),
+        # whichever form above built it.
+        step.__name__ = step.__qualname__ = "flow_serve_step"
         self.step_fn = step
         # donate the register buffers on accelerator backends: the update
         # rewrites the whole table every step, so the input buffers are
@@ -353,17 +355,17 @@ class StatefulPipeline:
         ``(state', verdict_device_array)``.  The async serving path
         (PacketServeEngine depth>1) chains dispatches through the returned
         state — the state dependency sequentializes in-flight batches —
-        and materializes verdicts lazily at flush time."""
-        import jax.numpy as jnp
-
-        X = jnp.asarray(X, jnp.float32)
-        if valid is None:
-            B = int(X.shape[0])
-            valid = self._ones_valid.get(B)
-            if valid is None:       # device-resident, reused every step
-                valid = self._ones_valid.setdefault(
-                    B, jnp.ones((B,), jnp.int32))
-        valid = jnp.asarray(valid, jnp.int32)
+        and materializes verdicts lazily at flush time.  The copy of rows
+        and mask to the device is named ``serve.put`` in a profile."""
+        with annotate("serve.put"):
+            X = jnp.asarray(X, jnp.float32)
+            if valid is None:
+                B = int(X.shape[0])
+                valid = self._ones_valid.get(B)
+                if valid is None:       # device-resident, reused every step
+                    valid = self._ones_valid.setdefault(
+                        B, jnp.ones((B,), jnp.int32))
+            valid = jnp.asarray(valid, jnp.int32)
         outs = self._step(*self._state_arrays(state), X, valid)
         return self._wrap_state(outs)
 

@@ -172,6 +172,7 @@ class _InFlight:
     out: Any                       # device array (lazy) or numpy (ready)
     t0: float                      # dispatch start
     ready: float | None            # completion time if known at dispatch
+    batch: int = 0                 # the engine's batch ordinal
     perm: Any = None               # sharded stateful: per-shard row indices
 
 
@@ -326,11 +327,8 @@ class PacketServeEngine:
         self._pending_swap: tuple | None = None
         self.stats_ = ServeStats(backend=self.backend, depth=self.depth)
         self._init_telemetry(telemetry, requested_backend)
-        if self._tel is not None:
-            with self._tel.tracer.span("warm_up", cat="compile",
-                                       backend=self.backend):
-                self._warm_up()
-        else:
+        with self._span("serve.warm_up", cat="compile",
+                        backend=self.backend):
             self._warm_up()
 
     # --------------------------------------------------------- telemetry
@@ -354,6 +352,14 @@ class PacketServeEngine:
         from repro import telemetry as T
 
         self._tel = T.resolve(telemetry)
+        # the ``serve.*`` vocabulary (docs/pipeline_ir.md#telemetry-
+        # contract): ``_span`` for what the ring keeps, ``_annotate`` for
+        # the phases of a batch, named only while a profile is taken.
+        # Without a plane a span only times its block (``dispatch_s``)
+        self._span = (T.untraced if self._tel is None
+                      else self._tel.tracer.span)
+        self._annotate = (T.unannotated if self._tel is None
+                          else T.annotate)
         self._tel_flowkey = None
         self._tel_slots = 0
         self._backend_children: dict[str, Any] = {}
@@ -458,10 +464,11 @@ class PacketServeEngine:
             or self.TELEMETRY_SEG_SAMPLE == 1
 
     def _record_dispatch(self, rows: np.ndarray, n: int, pad: int,
-                         t0: float, t1: float, slots=None) -> None:
-        """Per-batch hot-path recording: counters, the dispatch span and
-        (stateful pipelines) the slot-segmentation statistics mirroring
-        the fused kernel's lockstep-vs-drain schedule split.  ``slots`` is the
+                         dispatch_s: float, slots=None) -> None:
+        """Per-batch hot-path recording: counters, the dispatch-time
+        histogram and (stateful pipelines) the slot-segmentation
+        statistics mirroring the fused kernel's lockstep-vs-drain
+        schedule split.  ``slots`` is the
         precomputed per-row slot vector (sharded routing already holds
         the keys), ``None`` to compute here on sampled batches, or
         ``False`` when the caller sampled the batch OUT."""
@@ -475,10 +482,7 @@ class PacketServeEngine:
             child = self._backend_children[self.backend] = \
                 self._backend_counter.labels(backend=self.backend)
         child.inc(1)
-        tm["dispatch_ms"].observe((t1 - t0) * 1e3)
-        self._tel.tracer.record(
-            "dispatch", t0, t1,
-            args={"backend": self.backend, "rows": n, "pad": pad})
+        tm["dispatch_ms"].observe(dispatch_s * 1e3)
         if self._tel_flowkey is not None and slots is not False:
             if slots is None:
                 if not self._seg_tick():
@@ -499,7 +503,8 @@ class PacketServeEngine:
             return
         from repro.telemetry import table_health
 
-        h = table_health(self.state, self._health_keys)
+        with self._span("serve.health_scan"):
+            h = table_health(self.state, self._health_keys)
         self._health_keys = h.pop("keys")
         m = self._tel.metrics
         m.gauge("flow_occupied_slots",
@@ -555,7 +560,8 @@ class PacketServeEngine:
 
         The chunk is copied: callers typically reuse one read buffer per
         chunk, and the queue may hold rows across several flushes."""
-        pkts = np.array(packets, np.float32)   # always copies
+        with self._annotate("serve.submit"):
+            pkts = np.array(packets, np.float32)   # always copies
         if pkts.ndim == 1:
             pkts = pkts[None, :]
         if pkts.shape[1] != self.feature_dim:
@@ -610,34 +616,42 @@ class PacketServeEngine:
         self._staging_i = (self._staging_i + 1) % len(self._staging)
         return buf, valid
 
-    def _dispatch_batch(self, rows: np.ndarray) -> int:
-        """Stage + launch one batch; returns rows actually dispatched."""
-        self._maybe_install_swap()     # dispatch-ring boundary
-        n = len(rows)
+    def _dispatch_batch(self, n: int) -> int:
+        """Stage + launch the next ``n`` queued rows as one batch; returns
+        the rows dispatched.  Spans: ``serve.stage`` (swap boundary,
+        queue take, staging copy, valid mask), ``serve.dispatch`` (the
+        ``dispatch_s`` interval; a stateful pipeline's ``dispatch``
+        opens ``serve.put`` inside it for the rows and mask to the
+        device, the rest is the launch), then ``serve.record``."""
+        k = self.stats_.batches
         pad = self.max_batch - n
-        buf, valid = self._next_staging()
-        buf[:n] = rows
-        if pad:
-            buf[n:] = 0.0
-        t0 = time.perf_counter()
-        if not self._inflight:
-            self._mark = t0            # new active-serving span
-        if self._stateful:
-            valid[:n] = 1
+        with self._annotate("serve.stage", batch=k):
+            self._maybe_install_swap()     # dispatch-ring boundary
+            rows = self._take(n)
+            buf, valid = self._next_staging()
+            buf[:n] = rows
             if pad:
-                valid[n:] = 0
-            self.state, out = self._dispatch_fn(self.state, buf, valid)
-        else:
-            out = self._dispatch_fn(buf)
-        t1 = time.perf_counter()
+                buf[n:] = 0.0
+            if self._stateful:
+                valid[:n] = 1
+                if pad:
+                    valid[n:] = 0
+        with self._span("serve.dispatch", batch=k) as d:
+            if self._stateful:
+                self.state, out = self._dispatch_fn(self.state, buf, valid)
+            else:
+                out = self._dispatch_fn(buf)
+        if not self._inflight:
+            self._mark = d.t0              # new active-serving span
         # a numpy result was computed synchronously inside the dispatch
         # call; anything else is a lazy device handle fetched later
-        ready = t1 if isinstance(out, np.ndarray) else None
-        self.stats_.dispatch_s += t1 - t0
+        ready = d.t1 if isinstance(out, np.ndarray) else None
+        self.stats_.dispatch_s += d.t1 - d.t0
         self.stats_.count_batch(self._backend_key, n, pad)
         if self._tel is not None:
-            self._record_dispatch(rows, n, pad, t0, t1)
-        self._inflight.append(_InFlight(n, out, t0, ready))
+            with self._annotate("serve.record", batch=k):
+                self._record_dispatch(rows, n, pad, d.t1 - d.t0)
+        self._inflight.append(_InFlight(n, out, d.t0, ready, k))
         return n
 
     # ---------------------------------------------------------- hot swap
@@ -670,12 +684,10 @@ class PacketServeEngine:
                 f"{'stateful' if self._stateful else 'stateless'}, new "
                 f"pipeline is {'stateful' if stateful else 'stateless'}"
             )
-        payload = self._prepare_swap(pipeline)
+        actual = _pipeline_backend(pipeline)
+        with self._span("serve.swap_prepare", cat="swap", backend=actual):
+            payload = self._prepare_swap(pipeline)
         if self._tel is not None:
-            actual = _pipeline_backend(pipeline)
-            self._tel.tracer.record(
-                "swap_prepare", t_req, time.perf_counter(), cat="swap",
-                args={"backend": actual})
             reason = getattr(pipeline, "fallback_reason", None)
             if reason or (backend == "pallas"
                           and actual in ("interpret", "mixed")):
@@ -716,17 +728,15 @@ class PacketServeEngine:
             return
         payload, t_req = pending
         old_backend = self.backend
-        t0 = time.perf_counter()
-        self._install_swap(payload)
-        t1 = time.perf_counter()
-        lat_s = t1 - t_req
+        to = _pipeline_backend(payload["pipeline"])
+        with self._span("serve.swap_install", cat="swap",
+                        **{"from": old_backend, "to": to}) as sp:
+            self._install_swap(payload)
+        lat_s = sp.t1 - t_req
         self.stats_.record_swap(lat_s)
         if self._tel is not None:
             self._tm["swaps"].inc(1)
             self._tm["swap_lat_ms"].observe(lat_s * 1e3)
-            self._tel.tracer.record(
-                "swap_install", t0, t1, cat="swap",
-                args={"from": old_backend, "to": self.backend})
             self._tel.journal.emit(
                 "hot_swap", lat_ms=round(lat_s * 1e3, 3),
                 pkt_offset=int(self.stats_.packets),
@@ -767,31 +777,33 @@ class PacketServeEngine:
     def _fetch_one(self) -> np.ndarray:
         """Materialize the oldest in-flight batch (FIFO: arrival order)."""
         f = self._inflight.popleft()
-        v = np.asarray(f.out)          # blocks until the result exists
-        end = f.ready if f.ready is not None else time.perf_counter()
-        self.stats_.batch_lat_s.append(end - f.t0)
-        if self._mark is not None:
-            self.stats_.wall_s += max(0.0, end - self._mark)
-            self._mark = max(self._mark, end) if self._inflight else None
-        if self._tel is not None:
-            self._tm["batch_lat_ms"].observe((end - f.t0) * 1e3)
-            self._tel.tracer.record(
-                "batch", f.t0, end,
-                args={"backend": self.backend, "rows": f.n})
-        if f.perm is not None:
-            out = self._unshard(v, f)
+        with self._annotate("serve.fetch", batch=f.batch):
+            v = np.asarray(f.out)      # blocks until the result exists
+            end = f.ready if f.ready is not None else time.perf_counter()
+            self.stats_.batch_lat_s.append(end - f.t0)
+            if self._mark is not None:
+                self.stats_.wall_s += max(0.0, end - self._mark)
+                self._mark = max(self._mark, end) if self._inflight else None
+            if self._tel is not None:
+                self._tm["batch_lat_ms"].observe((end - f.t0) * 1e3)
+                self._tel.tracer.record(
+                    "serve.batch", f.t0, end,
+                    args={"batch": f.batch, "backend": self.backend,
+                          "rows": f.n})
+            if f.perm is not None:
+                out = self._unshard(v, f)
+            else:
+                out = v[:f.n]
+                # a plain-numpy pipeline may return a VIEW of its input —
+                # i.e. of a reusable staging buffer the next dispatch will
+                # overwrite; copy so returned verdicts can never be
+                # corrupted in place (device-array results are fresh
+                # buffers and never alias the ring)
+                if isinstance(f.out, np.ndarray) and any(
+                    np.shares_memory(out, buf) for buf in self._staging
+                ):
+                    out = out.copy()
             self._count_mitigated(out)
-            return out
-        out = v[:f.n]
-        # a plain-numpy pipeline may return a VIEW of its input — i.e. of a
-        # reusable staging buffer the next dispatch will overwrite; copy so
-        # returned verdicts can never be corrupted in place (device-array
-        # results are fresh buffers and never alias the ring)
-        if isinstance(f.out, np.ndarray) and any(
-            np.shares_memory(out, buf) for buf in self._staging
-        ):
-            out = out.copy()
-        self._count_mitigated(out)
         return out
 
     def _count_mitigated(self, verdicts: np.ndarray) -> None:
@@ -812,9 +824,7 @@ class PacketServeEngine:
         while self._pending:
             while len(self._inflight) >= self.depth:
                 outs.append(self._fetch_one())
-            self._dispatch_batch(
-                self._take(min(self.max_batch, self._pending))
-            )
+            self._dispatch_batch(min(self.max_batch, self._pending))
         while self._inflight:
             outs.append(self._fetch_one())
         # the ring is drained: a boundary — install any pending swap even
@@ -837,7 +847,7 @@ class PacketServeEngine:
             while self._pending >= self.max_batch:
                 while len(self._inflight) >= self.depth:
                     yield self._fetch_one()
-                self._dispatch_batch(self._take(self.max_batch))
+                self._dispatch_batch(self.max_batch)
         if self._pending or self._inflight:
             tail = self.flush()
             if len(tail):

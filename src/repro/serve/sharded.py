@@ -33,7 +33,6 @@ launch.  See docs/pipeline_ir.md#serving-performance-contract.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import numpy as np
 
@@ -201,62 +200,76 @@ class ShardedPacketServeEngine(PacketServeEngine):
         else:
             np.asarray(self._sharded_fn(zeros))
 
-    def _dispatch_batch(self, rows: np.ndarray) -> int:
+    def _dispatch_batch(self, n: int) -> int:
         if not self.sharded or not self._stateful:
-            return super()._dispatch_batch(rows)
+            return super()._dispatch_batch(n)
+        with self._annotate("serve.stage", batch=self.stats_.batches):
+            self._maybe_install_swap()     # dispatch-ring boundary
+            rows = self._take(n)
         return self._dispatch_routed(rows)
 
     def _dispatch_routed(self, rows: np.ndarray) -> int:
-        """Stateful sharding: route rows to their flow's device table."""
-        self._maybe_install_swap()     # dispatch-ring boundary
-        keys = self._flowkey.apply_keys_np(rows)
-        shard_ids = shard_of_key(keys, self.n_shards)
-        m, perm = route_prefix(shard_ids, self.n_shards, self._sub_batch)
-        if m < len(rows):
-            if self._tel is not None:
-                self._tm["overflow"].inc(len(rows) - m)
-            self._requeue_front(rows[m:].copy())
-        rows = rows[:m]
+        """Stateful sharding: route rows to their flow's device table.
+
+        Spans: ``serve.route`` (flow keys, shard ids, the prefix that
+        fits, the overflow pushed back), a second ``serve.stage`` (the
+        per-shard scatter into the staging buffer), then the base
+        engine's ``serve.dispatch`` (holding ``serve.put``; the rest is
+        the launch) and ``serve.record``."""
+        k = self.stats_.batches
+        annotate = self._annotate
+        with annotate("serve.route", batch=k):
+            keys = self._flowkey.apply_keys_np(rows)
+            shard_ids = shard_of_key(keys, self.n_shards)
+            m, perm = route_prefix(shard_ids, self.n_shards, self._sub_batch)
+            if m < len(rows):
+                if self._tel is not None:
+                    self._tm["overflow"].inc(len(rows) - m)
+                self._requeue_front(rows[m:].copy())
+            rows = rows[:m]
 
         b = self._sub_batch
-        buf, valid = self._next_staging()
-        x = buf.reshape(self.n_shards, b, self.feature_dim)
-        v = valid.reshape(self.n_shards, b)
-        x[:] = 0.0
-        v[:] = 0
-        for s, idx in enumerate(perm):
-            x[s, :len(idx)] = rows[idx]
-            v[s, :len(idx)] = 1
+        with annotate("serve.stage", batch=k):
+            buf, valid = self._next_staging()
+            x = buf.reshape(self.n_shards, b, self.feature_dim)
+            v = valid.reshape(self.n_shards, b)
+            x[:] = 0.0
+            v[:] = 0
+            for s, idx in enumerate(perm):
+                x[s, :len(idx)] = rows[idx]
+                v[s, :len(idx)] = 1
 
-        t0 = time.perf_counter()
+        with self._span("serve.dispatch", batch=k) as d:
+            self.state, out = self._launch_stateful(buf, valid)
         if not self._inflight:
-            self._mark = t0
-        self.state, out = self._launch_stateful(buf, valid)
-        t1 = time.perf_counter()
-        self.stats_.dispatch_s += t1 - t0
+            self._mark = d.t0
+        self.stats_.dispatch_s += d.t1 - d.t0
         self.stats_.count_batch(self._backend_key, m, self.max_batch - m)
         if self._tel is not None:
-            slots = False              # sampled out unless the tick fires
-            if self._seg_tick():
-                # the flow keys are already in hand: fold the shard id
-                # into the slot so same-slot chains on DIFFERENT devices
-                # never merge (each device walks its own table)
-                n_slots = int(self.state.spec.n_slots)
-                slots = (shard_ids[:m] * n_slots
-                         + self._hash_slot_np(keys[:m], n_slots))
-            self._record_dispatch(rows, m, self.max_batch - m, t0, t1,
-                                  slots=slots)
-        self._inflight.append(_InFlight(m, out, t0, None, perm=perm))
+            with annotate("serve.record", batch=k):
+                slots = False          # sampled out unless the tick fires
+                if self._seg_tick():
+                    # the flow keys are already in hand: fold the shard id
+                    # into the slot so same-slot chains on DIFFERENT
+                    # devices never merge (each device walks its own table)
+                    n_slots = int(self.state.spec.n_slots)
+                    slots = (shard_ids[:m] * n_slots
+                             + self._hash_slot_np(keys[:m], n_slots))
+                self._record_dispatch(rows, m, self.max_batch - m,
+                                      d.t1 - d.t0, slots=slots)
+        self._inflight.append(_InFlight(m, out, d.t0, None, k, perm=perm))
         return m
 
     def _launch_stateful(self, buf: np.ndarray, valid: np.ndarray):
-        """One sharded stateful step over the stacked register tables."""
+        """One sharded stateful step over the stacked register tables;
+        the copy of rows and mask to the devices is ``serve.put``."""
         import jax.numpy as jnp
 
         b = self._sub_batch
-        x = jnp.asarray(buf, jnp.float32).reshape(
-            self.n_shards, b, self.feature_dim)
-        v = jnp.asarray(valid, jnp.int32).reshape(self.n_shards, b)
+        with self._annotate("serve.put"):
+            x = jnp.asarray(buf, jnp.float32).reshape(
+                self.n_shards, b, self.feature_dim)
+            v = jnp.asarray(valid, jnp.int32).reshape(self.n_shards, b)
         outs = self._sharded_fn(*self.state.arrays(), x, v)
         return self.state.with_arrays(outs[:-1]), outs[-1]
 
@@ -430,14 +443,15 @@ def _build_sharded_step(traceable, devices, *, n_state: int):
     mesh = Mesh(np.array(devices), ("data",))
 
     if n_state:
-        def step(*args):
+        def flow_serve_step(*args):
             # each program sees its shard with the leading axis dropped,
-            # and returns it re-added: [1, …]
+            # and returns it re-added: [1, …]; the name is the step's
+            # stable name in a profile, as on one device
             outs = traceable(*(a[0] for a in args))
             return tuple(o[None] for o in outs)
 
         fn = jax.shard_map(
-            step, mesh=mesh,
+            flow_serve_step, mesh=mesh,
             in_specs=(P("data"),) * (n_state + 2),
             out_specs=(P("data"),) * (n_state + 1),
             check_vma=False,

@@ -5,8 +5,9 @@ One ``Telemetry`` object bundles the three observability surfaces
 
   * ``metrics``  — lock-free-on-the-hot-path counters/gauges/histograms
     with snapshot-on-read (``telemetry.metrics``);
-  * ``tracer``   — monotonic-clock spans in a bounded ring, exportable
-    as Chrome ``trace_event`` JSON (``telemetry.trace``);
+  * ``tracer``   — the engines' ``serve.*`` spans, on the profiler's
+    clock while a profile is taken and in a bounded ring exportable as
+    Chrome ``trace_event`` JSON (``telemetry.trace``);
   * ``journal``  — the append-only operator event log, JSON lines
     (``telemetry.journal``).
 
@@ -27,9 +28,15 @@ from repro.telemetry.metrics import (
     Histogram,
     MetricsRegistry,
 )
-from repro.telemetry.trace import Span, Tracer
+from repro.telemetry.trace import (
+    Span,
+    Tracer,
+    annotate,
+    unannotated,
+    untraced,
+)
 from repro.telemetry.journal import EVENT_KINDS, EventJournal
-from repro.telemetry.export import to_json, to_prometheus
+from repro.telemetry.export import to_prometheus
 from repro.telemetry.flow_health import (
     batch_segmentation,
     mitigation_residency,
@@ -43,10 +50,12 @@ __all__ = [
     "MetricsRegistry",
     "Span",
     "Tracer",
+    "untraced",
+    "annotate",
+    "unannotated",
     "EVENT_KINDS",
     "EventJournal",
     "Telemetry",
-    "to_json",
     "to_prometheus",
     "table_health",
     "batch_segmentation",
@@ -83,10 +92,6 @@ class Telemetry:
     def prometheus(self) -> str:
         """Current metrics in Prometheus text exposition format."""
         return to_prometheus(self.snapshot())
-
-    def json(self) -> str:
-        """Current metrics as a JSON document."""
-        return to_json(self.snapshot())
 
     def chrome_trace(self) -> dict:
         """Recorded spans as Chrome ``trace_event`` JSON (object form)."""

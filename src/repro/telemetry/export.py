@@ -1,6 +1,6 @@
-"""Exporters: Prometheus text format and JSON over a metrics snapshot.
+"""Exporter: Prometheus text format over a metrics snapshot.
 
-Both operate on ``MetricsRegistry.snapshot()`` output — a frozen copy —
+It operates on ``MetricsRegistry.snapshot()`` output — a frozen copy —
 so exporting never races the recording threads and costs the hot path
 nothing.  The Prometheus rendering follows the text exposition format
 (``# HELP`` / ``# TYPE`` headers, ``name{label="v"} value`` samples,
@@ -10,9 +10,7 @@ histogram ``_bucket``/``_sum``/``_count`` expansion with cumulative
 
 from __future__ import annotations
 
-import json
-
-__all__ = ["to_prometheus", "to_json"]
+__all__ = ["to_prometheus"]
 
 
 def _escape(v) -> str:
@@ -66,7 +64,3 @@ def to_prometheus(snapshot: dict) -> str:
                     f" {_fmt_value(val['value'])}")
     return "\n".join(lines) + ("\n" if lines else "")
 
-
-def to_json(snapshot: dict, *, indent: int | None = None) -> str:
-    """The snapshot as a JSON document (it is already JSON-clean)."""
-    return json.dumps(snapshot, indent=indent, sort_keys=True)

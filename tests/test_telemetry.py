@@ -1,7 +1,8 @@
 """Unified telemetry plane (docs/pipeline_ir.md#telemetry-contract), tier-1.
 
 Covers the three surfaces — metrics registry, span tracer, event
-journal — their exporters (Prometheus text, JSON, Chrome trace_event),
+journal — their exporters (Prometheus text, Chrome trace_event), the
+engines' ``serve.*`` spans and the step's stable name in a profile,
 the flow-table health scans, and the engine integration properties:
 counter totals equal packets served under arbitrary interleavings with
 hot swaps at depth > 1, bit-identical verdicts with telemetry on/off,
@@ -9,6 +10,10 @@ and the drift -> retrain -> swap -> mitigation event trail of a
 coordinated-DDoS replay."""
 
 import json
+import os
+import subprocess
+import sys
+import textwrap
 import threading
 
 import numpy as np
@@ -34,7 +39,6 @@ from repro.telemetry import (
     batch_segmentation,
     mitigation_residency,
     table_health,
-    to_json,
     to_prometheus,
 )
 from repro.telemetry.metrics import MetricsRegistry
@@ -122,6 +126,78 @@ def test_tracer_span_contextmanager_records_args():
     assert s.args == {"backend": "pallas"} and s.dur_s >= 0.0
 
 
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation`` while a profile is
+    taken: logs each annotation's name and metadata as it opens and
+    closes."""
+
+    def __init__(self):
+        self.log = []
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def __call__(self, name, **kwargs):
+        log = self.log
+
+        class Annotation:
+            def __enter__(self):
+                log.append(("open", name, kwargs))
+                return self
+
+            def __exit__(self, *exc):
+                log.append(("close", name, kwargs))
+                return False
+
+        return Annotation()
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax.profiler
+
+    rec = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec)
+    return rec
+
+
+def test_tracer_span_opens_profiler_annotation_and_fills_ring(annotations):
+    tr = Tracer()
+    with tr.span("serve.stage", batch=3) as sp:
+        # the annotation is open while the block runs; the ring is not
+        # written until the span closes
+        assert annotations.log == [("open", "serve.stage", {"batch": 3})]
+        assert len(tr) == 0
+    assert annotations.log[-1] == ("close", "serve.stage", {"batch": 3})
+    (s,) = tr.spans()
+    assert s.name == "serve.stage" and s.cat == "serve"
+    assert s.args == {"batch": 3}
+    assert s.dur_s == pytest.approx(sp.t1 - sp.t0)
+
+
+def test_telemetry_false_opens_no_annotation(annotations):
+    eng = PacketServeEngine(StatefulPipeline(_flow_stages(_spec())),
+                            feature_dim=2, max_batch=8, telemetry=False)
+    eng.submit(_flow_packets(np.random.default_rng(4), 20))
+    assert len(eng.flush()) == 20
+    # the engine opens none of its own; the pipeline's dispatch names its
+    # put in any profile, whoever calls it
+    assert {name for _, name, _ in annotations.log} == {"serve.put"}
+    assert eng.stats_.dispatch_s > 0.0      # still timed, just not traced
+
+
+def test_annotate_is_a_shared_no_op_without_a_profile():
+    import jax.profiler
+
+    from repro.telemetry.trace import annotate
+
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert annotate("serve.stage", batch=1) is annotate("serve.fetch")
+    with annotate("serve.stage", batch=1) as a:
+        assert a is not None
+
+
 # ------------------------------------------------------------------ journal
 
 
@@ -189,13 +265,6 @@ def test_prometheus_escapes_label_values():
     m = MetricsRegistry()
     m.counter("c").inc(1, path='a"b\\c')
     assert 'c{path="a\\"b\\\\c"} 1' in to_prometheus(m.snapshot())
-
-
-def test_json_export_parses_back():
-    m = MetricsRegistry()
-    m.counter("c").default.inc(2)
-    doc = json.loads(to_json(m.snapshot()))
-    assert doc["c"]["values"][0]["value"] == 2.0
 
 
 # -------------------------------------------------------------- flow health
@@ -330,12 +399,137 @@ def test_engine_counters_spans_and_prometheus_end_to_end():
     assert snap["serve_batch_latency_ms"]["values"][0]["count"] == 13
     # exporters render the live registry
     assert "serve_packets_total 100" in tel.prometheus()
-    assert json.loads(tel.json())["serve_packets_total"]
     # the trace has warm-up + dispatch + batch spans, Chrome-valid
     names = {s.name for s in tel.tracer.spans()}
-    assert {"warm_up", "dispatch", "batch"} <= names
+    assert {"serve.warm_up", "serve.dispatch", "serve.batch"} <= names
     for ev in tel.chrome_trace()["traceEvents"]:
         assert ev["ph"] == "X" and ev["dur"] >= 1
+
+
+def test_one_stateful_batch_emits_the_serve_spans_in_order(annotations):
+    eng = PacketServeEngine(StatefulPipeline(_flow_stages(_spec())),
+                            feature_dim=2, max_batch=8, depth=2)
+    tel = eng.telemetry()
+    tel.tracer.clear()
+    del annotations.log[:]
+    eng.submit(_flow_packets(np.random.default_rng(5), 8))
+    assert len(eng.flush()) == 8
+
+    # a profile sees every phase, nested as opened and closed: the put
+    # inside the dispatch, the rest of the dispatch is the launch
+    assert [(ev, name) for ev, name, _ in annotations.log] == [
+        ("open", "serve.submit"), ("close", "serve.submit"),
+        ("open", "serve.stage"), ("close", "serve.stage"),
+        ("open", "serve.dispatch"),
+        ("open", "serve.put"), ("close", "serve.put"),
+        ("close", "serve.dispatch"),
+        ("open", "serve.record"), ("close", "serve.record"),
+        ("open", "serve.fetch"), ("close", "serve.fetch"),
+        ("open", "serve.health_scan"), ("close", "serve.health_scan")]
+    # the engine's phases of one batch share its ordinal
+    assert {kw["batch"] for _, name, kw in annotations.log if name in (
+        "serve.stage", "serve.dispatch", "serve.record",
+        "serve.fetch")} == {0}
+    # the ring keeps what an operator reads back: the dispatch (the
+    # interval dispatch_s sums), the batch's lifetime, the scan
+    spans = tel.tracer.spans()
+    assert [s.name for s in spans] == [
+        "serve.dispatch", "serve.batch", "serve.health_scan"]
+    assert spans[0].args == {"batch": 0}
+    assert spans[0].dur_s == pytest.approx(eng.stats_.dispatch_s)
+    assert spans[1].args["batch"] == 0 and spans[1].args["rows"] == 8
+
+
+_ROUTE_SCRIPT = textwrap.dedent("""
+    import numpy as np
+    import jax
+    import jax.profiler
+    from repro.core import stageir
+    from repro.flowstate import FlowStateSpec, StatefulPipeline
+    from repro.serve import ShardedPacketServeEngine
+
+    assert len(jax.devices()) == 4
+    spec = FlowStateSpec(n_slots=32, n_counters=1, n_ewma=1,
+                         hist_sizes=(3,), ewma_alpha=0.5)
+    fk = stageir.FlowKey((0,), spec.n_slots)
+    ru = stageir.RegisterUpdate(spec, ewma_cols=(1,), hist_cols=(1,),
+                                hist_edges=(np.linspace(0, 1, 4)[1:-1],))
+    pipe = StatefulPipeline([fk, ru, stageir.WindowStats(spec, mode="all")])
+    eng = ShardedPacketServeEngine(pipe, feature_dim=2, max_batch=32,
+                                   depth=2)
+    assert eng.sharded and eng.n_shards == 4
+    log = []
+
+    # stands in for the profiler's annotation while a profile is taken:
+    # logs each name and its metadata as it opens
+    class Annotation:
+        def __init__(self, name, **kwargs):
+            self.name, self.kwargs = name, kwargs
+
+        @staticmethod
+        def is_enabled():
+            return True
+
+        def __enter__(self):
+            log.append((self.name, self.kwargs))
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    jax.profiler.TraceAnnotation = Annotation
+    X = np.zeros((64, 2), np.float32)
+    X[:, 0] = np.random.default_rng(0).integers(0, 40, 64)
+    eng.submit(X)
+    assert len(eng.flush()) == 64
+    names = [name for name, _ in log]
+    n = names.count("serve.dispatch")
+    assert n >= 2 and names.count("serve.route") == n
+    assert names.count("serve.put") == n
+    assert names.count("serve.stage") == 2 * n
+    assert names.count("serve.record") == names.count("serve.fetch") == n
+    for k in range(n):
+        assert {name for name, kw in log if kw.get("batch") == k} >= {
+            "serve.stage", "serve.route", "serve.dispatch",
+            "serve.record", "serve.fetch"}
+    print("ROUTE-OK")
+""")
+
+
+def test_sharded_engine_emits_route_span_on_four_devices():
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4")
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in ("src", env.get("PYTHONPATH", "")) if p)
+    proc = subprocess.run(
+        [sys.executable, "-c", _ROUTE_SCRIPT], capture_output=True,
+        text=True, timeout=600, env=env,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert "ROUTE-OK" in proc.stdout
+
+
+@pytest.mark.parametrize("sharded", [False, True])
+def test_step_module_has_a_stable_name(sharded):
+    """The jitted step is ``flow_serve_step`` on one device and under
+    shard_map (here a 1-ary mesh), so a profile's module line names it
+    ``jit_flow_serve_step`` whatever the backend lowered."""
+    import jax.numpy as jnp
+
+    from repro.serve import ShardedPacketServeEngine
+
+    pipe = StatefulPipeline(_flow_stages(_spec()))
+    x, v = jnp.zeros((8, 2), jnp.float32), jnp.zeros((8,), jnp.int32)
+    if sharded:
+        eng = ShardedPacketServeEngine(pipe, feature_dim=2, max_batch=8,
+                                       min_shards=1, telemetry=False)
+        lowered = eng._sharded_fn.lower(*eng.state.arrays(), x[None],
+                                        v[None])
+    else:
+        lowered = pipe._step.lower(*pipe._state_arrays(pipe.init_state()),
+                                   x, v)
+    assert "module @jit_flow_serve_step" in lowered.as_text()
 
 
 def test_telemetry_false_disables_recording_and_keeps_verdicts():
@@ -515,6 +709,7 @@ def test_coordinated_ddos_replay_event_trail():
     assert first["drift"] < first["retrain_start"] < first["hot_swap"]
     # Chrome trace validates structurally and serializes
     ct = tel.chrome_trace()
-    assert {"warm_up", "dispatch", "batch", "swap_install"} <= {
+    assert {"serve.warm_up", "serve.dispatch", "serve.batch",
+            "serve.swap_install"} <= {
         e["name"] for e in ct["traceEvents"]}
     json.dumps(ct)
