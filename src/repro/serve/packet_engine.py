@@ -415,6 +415,13 @@ class PacketServeEngine:
                 "rows pushed back to the queue head because their "
                 "shard's sub-batch filled (sharded routing)"
             ).default,
+            "resharded": m.gauge(
+                "serve_resharded_step_args",
+                "sharded step arguments (tables, rows, mask) not placed "
+                "with the step's input sharding, so the launch re-slices "
+                "or copies them between devices (set at warm-up and swap "
+                "install)"
+            ).default,
         }
         self._backend_counter = m.counter(
             "serve_backend_batches_total",

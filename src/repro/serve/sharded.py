@@ -27,7 +27,10 @@
 
 The dispatch-pipeline ``depth`` machinery (overlap, lazy fetch, staging
 ring) is inherited unchanged; the sharded step is just a different
-launch.  See docs/pipeline_ir.md#serving-performance-contract.
+launch.  Each batch's rows and mask, and the tables, are placed with the
+step's own input sharding (every device receives its sub-batch straight
+from the host), so the launch re-slices nothing and copies nothing
+between devices.  See docs/pipeline_ir.md#serving-performance-contract.
 """
 
 from __future__ import annotations
@@ -166,7 +169,7 @@ class ShardedPacketServeEngine(PacketServeEngine):
         self.n_shards = n
         self._sub_batch = -(-int(max_batch) // n)       # ceil
         stateful = hasattr(pipeline, "init_state")
-        self._mesh, self._sharded_fn = _build_sharded_step(
+        self._sharding, self._sharded_fn = _build_sharded_step(
             traceable, devices, n_state=_n_state(pipeline) if stateful else 0
         )
         if stateful:
@@ -175,7 +178,7 @@ class ShardedPacketServeEngine(PacketServeEngine):
             self._flowkey = next(s for s in pipeline.stages
                                  if isinstance(s, stageir.FlowKey))
             if state is None:
-                state = _init_sharded_state(pipeline, n)
+                state = _init_sharded_state(pipeline, self._sharding)
         super().__init__(pipeline, feature_dim=feature_dim,
                          max_batch=self._sub_batch * n, state=state,
                          depth=depth, telemetry=telemetry)
@@ -193,6 +196,7 @@ class ShardedPacketServeEngine(PacketServeEngine):
             return super()._warm_up()
         zeros = np.zeros((self.max_batch, self.feature_dim), np.float32)
         if self._stateful:
+            self._gauge_resharded()
             state, out = self._launch_stateful(
                 zeros, np.zeros(self.max_batch, np.int32))
             self.state = state
@@ -263,15 +267,35 @@ class ShardedPacketServeEngine(PacketServeEngine):
     def _launch_stateful(self, buf: np.ndarray, valid: np.ndarray):
         """One sharded stateful step over the stacked register tables;
         the copy of rows and mask to the devices is ``serve.put``."""
-        import jax.numpy as jnp
-
-        b = self._sub_batch
         with self._annotate("serve.put"):
-            x = jnp.asarray(buf, jnp.float32).reshape(
-                self.n_shards, b, self.feature_dim)
-            v = jnp.asarray(valid, jnp.int32).reshape(self.n_shards, b)
+            x, v = self._place(buf, valid)
         outs = self._sharded_fn(*self.state.arrays(), x, v)
         return self.state.with_arrays(outs[:-1]), outs[-1]
+
+    def _place(self, buf: np.ndarray, valid: np.ndarray) -> tuple:
+        """Rows and mask as the step reads them: the host buffers viewed
+        as ``[n_shards, sub_batch, ...]`` and put in one batched call, so
+        each device receives its own sub-batch straight from the host."""
+        import jax
+
+        b = self._sub_batch
+        return jax.device_put(
+            (buf.reshape(self.n_shards, b, self.feature_dim),
+             valid.reshape(self.n_shards, b)), self._sharding)
+
+    def _gauge_resharded(self) -> None:
+        """Set ``serve_resharded_step_args``: the step arguments (tables,
+        then a batch's rows and mask) whose sharding is not the step's
+        input sharding — each one the launch would re-slice or copy
+        between devices.  Warm-up and swap install only."""
+        if self._tel is None:
+            return
+        args = (*self.state.arrays(), *self._place(
+            np.zeros((self.max_batch, self.feature_dim), np.float32),
+            np.zeros(self.max_batch, np.int32)))
+        self._tm["resharded"].set(sum(
+            not a.sharding.is_equivalent_to(self._sharding, a.ndim)
+            for a in args))
 
     def _unshard(self, v: np.ndarray, f: _InFlight) -> np.ndarray:
         """Scatter per-shard outputs (verdicts, or feature rows when the
@@ -309,11 +333,11 @@ class ShardedPacketServeEngine(PacketServeEngine):
                 "engine (flows are key-partitioned on ONE flow key)"
             )
         payload = {"pipeline": pipeline}
-        mesh, fn = _build_sharded_step(
+        sharding, fn = _build_sharded_step(
             traceable, self.devices,
             n_state=_n_state(pipeline) if self._stateful else 0,
         )
-        payload["mesh"], payload["fn"] = mesh, fn
+        payload["sharding"], payload["fn"] = sharding, fn
         b = self._sub_batch
         if self._stateful:
             from repro.core import stageir
@@ -328,11 +352,12 @@ class ShardedPacketServeEngine(PacketServeEngine):
                     f"{tuple(flowkey.key_cols)}"
                 )
             payload["flowkey"] = flowkey
-            tmp = _init_sharded_state(pipeline, self.n_shards)
-            import jax.numpy as jnp
+            tmp = _init_sharded_state(pipeline, sharding)
+            import jax
 
-            x = jnp.zeros((self.n_shards, b, self.feature_dim), jnp.float32)
-            v = jnp.zeros((self.n_shards, b), jnp.int32)
+            x, v = jax.device_put(
+                (np.zeros((self.n_shards, b, self.feature_dim), np.float32),
+                 np.zeros((self.n_shards, b), np.int32)), sharding)
             np.asarray(fn(*tmp.arrays(), x, v)[-1])
         else:
             np.asarray(fn(np.zeros((self.max_batch, self.feature_dim),
@@ -344,9 +369,10 @@ class ShardedPacketServeEngine(PacketServeEngine):
             return super()._install_swap(payload)
         super()._install_swap(payload)
         self._sharded_fn = payload["fn"]
-        self._mesh = payload["mesh"]
+        self._sharding = payload["sharding"]
         if self._stateful:
             self._flowkey = payload["flowkey"]
+            self._gauge_resharded()
         else:
             self._dispatch_fn = self._sharded_fn
 
@@ -436,11 +462,13 @@ def _build_sharded_step(traceable, devices, *, n_state: int):
     traceable step threads (0 = stateless; 2 = flow tables; 4 = flow +
     mitigation action tables) — the step signature is ``(*state, x,
     valid) -> (*state', verdicts)`` with every array sharded on its
-    leading axis."""
+    leading axis.  Returns ``(sharding, step)``: ``sharding`` is that
+    input sharding, for placing arguments where the step reads them."""
     import jax
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     mesh = Mesh(np.array(devices), ("data",))
+    sharding = NamedSharding(mesh, P("data"))
 
     if n_state:
         def flow_serve_step(*args):
@@ -456,7 +484,7 @@ def _build_sharded_step(traceable, devices, *, n_state: int):
             out_specs=(P("data"),) * (n_state + 1),
             check_vma=False,
         )
-        return mesh, jax.jit(fn)
+        return sharding, jax.jit(fn)
 
     fn = jax.shard_map(lambda x: traceable(x), mesh=mesh,
                        in_specs=(P("data"),), out_specs=P("data"),
@@ -464,26 +492,33 @@ def _build_sharded_step(traceable, devices, *, n_state: int):
     jitted = jax.jit(fn)
 
     def dispatch(buf):
-        import jax.numpy as jnp
+        return jitted(jax.device_put(buf, sharding))
 
-        return jitted(jnp.asarray(buf, jnp.float32))
-
-    return mesh, dispatch
+    return sharding, dispatch
 
 
-def _init_sharded_state(pipeline, n_shards: int) -> ShardedFlowState:
+def _init_sharded_state(pipeline, sharding) -> ShardedFlowState:
+    """Empty per-device tables, each made on its own device by one
+    program partitioned with ``sharding`` (nothing is copied there)."""
+    import jax
     import jax.numpy as jnp
 
+    n = sharding.mesh.size
     spec = pipeline.spec
-    keys = jnp.full((n_shards, spec.n_slots), -1, jnp.int32)
-    regs = jnp.zeros((n_shards, spec.n_slots, spec.width), jnp.float32)
     mit = getattr(pipeline, "mitigation", None)
-    if mit is None:
-        return ShardedFlowState(spec, keys, regs)
-    from repro.flowstate.mitigation import MIT_WIDTH
 
-    return ShardedFlowState(
-        spec, keys, regs, mit,
-        jnp.full((n_shards, mit.n_slots), -1, jnp.int32),
-        jnp.zeros((n_shards, mit.n_slots, MIT_WIDTH), jnp.float32),
-    )
+    def empty():
+        tables = (jnp.full((n, spec.n_slots), -1, jnp.int32),
+                  jnp.zeros((n, spec.n_slots, spec.width), jnp.float32))
+        if mit is None:
+            return tables
+        from repro.flowstate.mitigation import MIT_WIDTH
+
+        return tables + (
+            jnp.full((n, mit.n_slots), -1, jnp.int32),
+            jnp.zeros((n, mit.n_slots, MIT_WIDTH), jnp.float32))
+
+    tables = jax.jit(empty, out_shardings=sharding)()
+    if mit is None:
+        return ShardedFlowState(spec, *tables)
+    return ShardedFlowState(spec, *tables[:2], mit, *tables[2:])

@@ -2,8 +2,9 @@
 
 One-device hosts exercise the full shard_map serving step by forcing
 ``min_shards=1`` (a 1-ary mesh is still a mesh); the true multi-device
-behavior is pinned by a subprocess test that forces 4 host CPU devices
-(slow).  The routing helpers are pure functions tested directly."""
+behavior is pinned by subprocess tests that force 4 host CPU devices
+(parity, slow; placement without device-to-device copies).  The routing
+helpers are pure functions tested directly."""
 
 import os
 import subprocess
@@ -306,3 +307,83 @@ def test_multi_device_parity_subprocess():
     )
     assert proc.returncode == 0, proc.stderr[-4000:]
     assert "MULTIDEV-OK" in proc.stdout
+
+
+_PLACEMENT_SCRIPT = textwrap.dedent("""
+    import numpy as np
+    import jax
+    assert len(jax.devices()) == 4, jax.devices()
+    from repro.core import stageir
+    from repro.flowstate import FlowStateSpec, MitigationSpec, StatefulPipeline
+    from repro.serve import PacketServeEngine, ShardedPacketServeEngine
+    from repro.serve.sharded import shard_of_key
+
+    SLOTS = 1 << 10
+
+    def flow_pipe(mitigated):
+        spec = FlowStateSpec(n_slots=SLOTS, n_counters=1, n_ewma=1,
+                             hist_sizes=(3,), ewma_alpha=0.5)
+        ws = stageir.WindowStats(spec, mode="all")
+        stages = [stageir.FlowKey((0,), SLOTS),
+                  stageir.RegisterUpdate(
+                      spec, ewma_cols=(1,), hist_cols=(1,),
+                      hist_edges=(np.linspace(0, 1, 4)[1:-1],)),
+                  ws]
+        if mitigated:             # says attack for every packet: drops
+            w = np.zeros((ws.n_out, 2), np.float32)
+            stages += [stageir.FusedMLP([w], [np.float32([0.0, 1.0])]),
+                       stageir.Reduce("argmax"),
+                       stageir.Mitigate(MitigationSpec(
+                           n_slots=SLOTS, mode="drop", threshold=3))]
+        return StatefulPipeline(stages, backend="interpret")
+
+    rng = np.random.default_rng(5)
+    X = np.zeros((300, 2), np.float32)
+    X[:, 0] = rng.integers(0, 40, 300)
+    X[:, 1] = rng.random(300)
+    ids = shard_of_key(stageir.FlowKey((0,), SLOTS).apply_keys_np(X), 4)
+    for mitigated in (False, True):
+        # tables, rows and mask must already sit where the step reads
+        # them: any re-slice or copy between devices raises here
+        with jax.transfer_guard_device_to_device("disallow"):
+            eng = ShardedPacketServeEngine(flow_pipe(mitigated),
+                                           feature_dim=2, max_batch=64,
+                                           depth=2)
+            assert eng.sharded and eng.n_shards == 4
+            got = []
+            for chunk in np.array_split(X, 3):
+                eng.submit(chunk)
+                got.append(eng.flush())
+        got = np.concatenate(got)
+        ref = np.empty_like(got)
+        for s in range(4):
+            e = PacketServeEngine(flow_pipe(mitigated), feature_dim=2,
+                                  max_batch=16)
+            e.submit(X[ids == s])
+            ref[ids == s] = e.flush()
+        np.testing.assert_array_equal(got, ref)
+        assert (got < 0).any() == mitigated
+        resharded = eng.telemetry().metrics.get("serve_resharded_step_args")
+        assert resharded.value() == 0, resharded.value()
+    print("PLACEMENT-OK")
+""")
+
+
+def test_sharded_inputs_placed_without_device_copies():
+    """Force 4 host CPU devices in a subprocess: a plain and a mitigated
+    stateful engine serve under a transfer guard that forbids
+    device-to-device copies, match the per-shard references, and count
+    no step argument off the step's input sharding."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4")
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in ("src", env.get("PYTHONPATH", "")) if p
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", _PLACEMENT_SCRIPT],
+        capture_output=True, text=True, timeout=120, env=env,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert "PLACEMENT-OK" in proc.stdout
