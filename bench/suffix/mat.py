@@ -34,6 +34,12 @@ def stages(p: dict) -> list:
             stageir.LabelMap(p["label_map"].astype(np.int32))]
 
 
+def ops(cfg: dict) -> int:
+    """Necessary operations per packet: per feature one compare per edge
+    and one add per id score."""
+    return int(cfg["n_in"]) * (int(cfg["n_edges"]) + int(cfg["n_ids"]))
+
+
 def _high(t: np.ndarray) -> np.ndarray:
     """A float32 table value as a one-hot matmul at ``Precision.HIGH``
     delivers it: its two leading bfloat16 parts."""

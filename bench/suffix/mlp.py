@@ -40,6 +40,16 @@ def stages(p: dict) -> list:
             stageir.Reduce("argmax")]
 
 
+def ops(cfg: dict) -> int:
+    """Necessary operations per packet: ``2 * n_in * n_out`` per layer,
+    its biases, and a ReLU per unit on every layer but the last."""
+    widths = [int(x) for x in cfg["widths"]]
+    n = 0
+    for i, (a, b) in enumerate(zip(widths[:-1], widths[1:])):
+        n += 2 * a * b + b + (b if i < len(widths) - 2 else 0)
+    return n
+
+
 def _dot_high(x: np.ndarray, w: np.ndarray) -> np.ndarray:
     """float32 matmul in three bfloat16 passes (``Precision.HIGH``):
     hi*hi + hi*lo + lo*hi, accumulated in float32."""

@@ -32,6 +32,15 @@ def test_mat_mitigated_config_per_packet():
     assert work.ops_per_packet(c) == 28 * 11 + 10 + 24 + 4 == 346
 
 
+@pytest.mark.parametrize("name, ops", [("flow-ddos-mlp", 1268 - 10 - 24),
+                                       ("mitigate-mat", 346 - 10 - 24 - 4)])
+def test_suffix_module_counts_its_ops(name, ops):
+    # the suffix's share of the hand counts above: all but the register
+    # update, the readout and the action row
+    suffix = _config(name)["suffix"]
+    assert spec.suffix_kind(suffix["kind"]).ops(suffix) == ops
+
+
 def test_least_seconds_bound_and_peaks():
     c = _config("flow-ddos-mlp")
     t, bound = work.least_seconds(c, 1_000_000, "TPU v5 lite")

@@ -5,12 +5,12 @@ What a packet needs, whatever implements it:
 * bytes: its packet row in (``FEATURE_COLS`` float32 words), its slot's
   register row read and written (``2 * width`` words), the stored key read
   and written (2 words), its verdict out (1 word); with mitigation also
-  the action row read and written (2 * 2 words) and its key (2 words);
+  the action row read and written (2 * 2 words) and its key (2 words).
+  A suffix's weights and tables are constants of the launch and add none;
 * operations: the register update (one add per counter, three per EWMA,
   one per histogram), the readout's divisions (one per histogram bin),
-  and the suffix's: ``2 * n_in * n_out`` per MLP layer plus its biases
-  and ReLUs, or per MAT feature one compare per edge and one add per id
-  score; with mitigation four more.
+  the suffix's, which its kind's module counts (``ops`` in
+  ``bench/suffix/<kind>.py``), and with mitigation four more.
 
 A kernel's roofline share and the step's utilisation divide the least
 time of this work at the chip's peaks by a measured time.
@@ -21,9 +21,12 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
+from bench import spec
+
 WORD = 4
 FEATURE_COLS = 4
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
+SUFFIX_DIR = spec.BENCH / "suffix"
 
 
 def register_width(prefix: dict) -> int:
@@ -38,24 +41,21 @@ def bytes_per_packet(config: dict) -> int:
     return words * WORD
 
 
-def suffix_ops(suffix: dict) -> int:
-    if suffix["kind"] == "mlp":
-        widths = [int(x) for x in suffix["widths"]]
-        ops = 0
-        for i, (a, b) in enumerate(zip(widths[:-1], widths[1:])):
-            ops += 2 * a * b + b + (b if i < len(widths) - 2 else 0)
-        return ops
-    if suffix["kind"] == "mat":
-        return int(suffix["n_in"]) * (int(suffix["n_edges"])
-                                      + int(suffix["n_ids"]))
-    raise KeyError(f"no operation count for suffix kind {suffix['kind']!r}")
+def suffix_ops(suffix: dict, suffix_dir: Path = SUFFIX_DIR) -> int:
+    """Operations per packet of the configuration's ``suffix``, from its
+    kind's module; a kind whose module has no ``ops`` is an error."""
+    count = getattr(spec.suffix_kind(suffix["kind"], suffix_dir), "ops", None)
+    if count is None:
+        raise KeyError(f"no operation count for suffix kind "
+                       f"{suffix['kind']!r}: its module has no ops()")
+    return int(count(suffix))
 
 
-def ops_per_packet(config: dict) -> int:
+def ops_per_packet(config: dict, suffix_dir: Path = SUFFIX_DIR) -> int:
     pre = config["prefix"]
     hist = int(pre["pl_bins"]) + int(pre["ipt_bins"])
     update = 2 + 3 * 2 + 2
-    ops = update + hist + suffix_ops(config["suffix"])
+    ops = update + hist + suffix_ops(config["suffix"], suffix_dir)
     if config.get("mitigation"):
         ops += 4
     return ops
