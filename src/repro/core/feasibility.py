@@ -316,6 +316,34 @@ class TPUModel:
         }
 
 
+# ------------------------------------------------------- fused forest
+#
+# A TreeTraverse forest served inside the fused flow launch
+# (kernels/fused_flow.forest_votes) keeps its heap-laid parameters and one
+# tree's per-level temporaries in the launch's VMEM beside the flow
+# tables' blocks.  The lowering asks here first and declines, with this
+# reason, a forest that would not fit.
+
+# the forest's share of the launch's VMEM: the kernel raises its scoped
+# limit to twice its estimate, capped at 96 MiB, so half of that ceiling
+# less room for the tables' blocks
+FOREST_VMEM_BUDGET = 32 * 2**20
+# the serving batch the launch is planned for (the engines' 512 rows)
+FUSED_BATCH = 512
+
+
+def fused_forest_reason(n_trees: int, depth: int) -> str | None:
+    """Why a forest of ``n_trees`` complete trees of ``depth`` levels does
+    not fit the fused launch (None = it fits)."""
+    from repro.kernels.fused_flow import forest_vmem_bytes
+
+    need = forest_vmem_bytes(n_trees, depth, FUSED_BATCH)
+    if need > FOREST_VMEM_BUDGET:
+        return (f"forest of {n_trees} trees of depth {depth} needs {need} B "
+                f"VMEM > {FOREST_VMEM_BUDGET} budget")
+    return None
+
+
 # -------------------------------------------------------------- flow state
 #
 # The per-flow register file (repro.flowstate) is a CO-RESIDENT on the

@@ -27,7 +27,9 @@ hash -> gather -> update -> scatter dataflow around ONE kernel launch,
 with the register table in HBM and only the batch's rows in VMEM.
 
 Everything else (``CentroidDistance``, ``TreeTraverse``, out-of-envelope
-shapes) returns ``None`` and the caller falls back to the interpreter —
+shapes) returns ``None`` here and the caller falls back to the
+interpreter — a stateful pipeline serves both inside the fused flow
+launch (``lower_stateful_fused``) instead —
 ``compile_stages``/``compile_dag``/``PacketServeEngine`` record which
 backend actually serves.
 
@@ -56,6 +58,7 @@ from repro.core.stageir import (
     Reduce,
     RegisterUpdate,
     Stage,
+    TreeTraverse,
     WindowStats,
 )
 
@@ -563,6 +566,14 @@ def _match_centroid(stages: list[Stage]):
     return fidx, cent, lmap, body[1].op == "argmin"
 
 
+def _match_tree(stages: list[Stage]):
+    """-> the ``TreeTraverse`` when the stage run is that one stage (a
+    forest's majority vote, or one tree's leaf class), else None."""
+    if len(stages) == 1 and isinstance(stages[0], TreeTraverse):
+        return stages[0]
+    return None
+
+
 def _as_table_groups(prefix_or_groups):
     """Normalize the prefix argument: a plain ``[FlowKey, RegisterUpdate]``
     prefix (the single-table form) or a ``split_stateful_multi`` group
@@ -649,6 +660,18 @@ def _plan_fused(prefix_or_groups, suffix: list[Stage], mitigation=None):
             if not _in_envelope_mat(tables, lmap):
                 return None, "MAT shape outside the kernel envelope"
             sfx = ("mat", edges, tables, lmap, use_min)
+        elif (tree := _match_tree(body)) is not None:
+            from repro.core import feasibility
+
+            feat, thr, leaf, depth = tree.heap()
+            if n_in > FF_LANE or tree.n_classes > FF_LANE:
+                return None, "forest width exceeds the kernel lane"
+            if int(tree.feat.max(initial=0)) >= n_in:
+                return None, "classifier input width mismatch"
+            reason = feasibility.fused_forest_reason(tree.n_trees, depth)
+            if reason:
+                return None, reason
+            sfx = ("forest", feat, thr, leaf, tree.n_classes, n_in)
         else:
             cen = _match_centroid(body)
             if cen is None:
@@ -698,7 +721,9 @@ def _pack_suffix(sfx, tile: int, interpret: bool):
     (``fused_mlp.pack_params``), +inf-padded MAT edges / zero-padded
     tables (the exact ``mat_lut.mat_classify`` convention, so the in-
     kernel replay sees identical operands), zero-padded centroid rows
-    (pad lanes contribute exact zeros to the squared distances)."""
+    (pad lanes contribute exact zeros to the squared distances), the
+    forest's heap-laid rows (``TreeTraverse.heap``) padded to whole lane
+    tiles with columns no walk reaches."""
     import jax.numpy as jnp
 
     from repro.kernels.fused_flow import SuffixPlan
@@ -738,6 +763,21 @@ def _pack_suffix(sfx, tile: int, interpret: bool):
         )[None, :]
         sp = SuffixPlan("mat", int(C), n_features=int(F), use_min=use_min)
         return sp, (edges_j, tables_j, lmap_j)
+    if sfx[0] == "forest":
+        _, feat, thr, leaf, n_classes, n_in = sfx
+        T, n = feat.shape
+        depth = n.bit_length() - 1
+        # the readout, the node-feature one-hots and the votes share one
+        # power-of-two lane width, so each deeper level's window of the
+        # heap is tile-aligned (``fused_flow.kernel._level_windows``)
+        lane = max(tile, 1 << (max(n_in, n_classes) - 1).bit_length())
+        cols = ((0, 0), (0, 0), (0, _snap(n, lane) - n))
+        arrays = (jnp.pad(jnp.asarray(feat, jnp.int32)[:, None], cols),
+                  jnp.pad(jnp.asarray(thr, jnp.float32)[:, None], cols),
+                  jnp.pad(jnp.asarray(leaf, jnp.float32)[:, None], cols))
+        sp = SuffixPlan("forest", int(n_classes), lane=lane,
+                        n_trees=int(T), depth=depth)
+        return sp, arrays
     _, fidx, cent, lmap, use_min = sfx
     K, Fp = cent.shape
     nk = lmap.shape[0]
@@ -805,4 +845,16 @@ def lower_stateful_fused(prefix_or_groups, suffix: list[Stage],
             interpret=_interp,
         )
 
+    fused_fn.suffix_counters = suffix_counters(sp, arrays)
     return fused_fn
+
+
+def suffix_counters(sp, arrays) -> dict:
+    """The fused launch's classifier as counters: its kind, the forest's
+    trees and levels (0 for the other kinds), the node visits a packet
+    makes (trees x levels of the complete trees) and the packed parameter
+    bytes the launch holds in VMEM."""
+    return {"kind": sp.kind, "trees": sp.n_trees, "depth": sp.depth,
+            "node_visits_per_packet": sp.n_trees * sp.depth,
+            "param_bytes": int(sum(a.size * a.dtype.itemsize
+                                   for a in arrays))}

@@ -13,8 +13,9 @@ paper's templates instantiate on hardware:
   ``Quantize``          per-feature range tables: value -> bucket id
   ``LUTGather``         per-feature MATs: bucket -> per-class partials,
                         summed across features
-  ``TreeTraverse``      level-synchronous decision-tree walk (one MAT per
-                        level on a switch)
+  ``TreeTraverse``      level-synchronous decision-tree walks with a
+                        majority vote over the trees (one MAT per level
+                        on a switch; one tree is the T = 1 forest)
   ``Reduce``            argmax / argmin over class scores
   ``LabelMap``          cluster/leaf id -> class id
 
@@ -237,56 +238,122 @@ class LUTGather(Stage):
 
 @dataclasses.dataclass(repr=False)
 class TreeTraverse(Stage):
-    """Level-synchronous CART walk: ``depth`` rounds of gather/compare —
-    the tensor form of one MAT per tree level."""
+    """A forest of level-synchronous CART walks with a hard majority vote.
 
-    feat: np.ndarray                     # [n_nodes] split feature (0 at leaf)
-    thr: np.ndarray                      # [n_nodes] f32 threshold
-    left: np.ndarray                     # [n_nodes] child ids (self at leaf)
+    Each of the T trees walks ``depth + 1`` rounds of gather/compare
+    (``x[feat] <= thr`` goes left, as scikit-learn splits) to its leaf
+    class; the verdict is the class with the most votes over the trees,
+    ties to the lowest class id (as ``Reduce("argmax")`` breaks them).
+    One tree is the T = 1 case: its vote is its leaf class.  Node arrays
+    carry a leading tree axis, [T, N]; a tree's root is node 0."""
+
+    feat: np.ndarray                     # [T, N] split feature (0 at leaf)
+    thr: np.ndarray                      # [T, N] f32 threshold
+    left: np.ndarray                     # [T, N] child ids (self at leaf)
     right: np.ndarray
-    leaf_class: np.ndarray               # [n_nodes] class at leaf (0 inner)
-    is_leaf: np.ndarray                  # [n_nodes] bool
+    leaf_class: np.ndarray               # [T, N] class at leaf (0 inner)
+    is_leaf: np.ndarray                  # [T, N] bool
     depth: int
 
     kind = "tree_traverse"
 
+    def __post_init__(self):
+        for name, dt in (("feat", np.int32), ("thr", np.float32),
+                         ("left", np.int32), ("right", np.int32),
+                         ("leaf_class", np.int32), ("is_leaf", bool)):
+            setattr(self, name, np.atleast_2d(np.asarray(getattr(self, name),
+                                                         dt)))
+
     @classmethod
     def from_nodes(cls, nodes: list[dict], depth: int) -> "TreeTraverse":
-        n = len(nodes)
-        feat = np.zeros(n, np.int32)
-        thr = np.zeros(n, np.float32)
-        left = np.arange(n, dtype=np.int32)
-        right = np.arange(n, dtype=np.int32)
-        leaf_class = np.zeros(n, np.int32)
-        is_leaf = np.zeros(n, bool)
-        for i, nd in enumerate(nodes):
-            if "leaf" in nd:
-                is_leaf[i] = True
-                leaf_class[i] = nd["leaf"]
-            else:
-                feat[i] = nd["feat"]
-                thr[i] = np.float32(nd["thr"])
-                left[i] = nd["left"]
-                right[i] = nd["right"]
+        """One CART tree (``mlalgos.train_tree``'s flat nodes)."""
+        return cls.from_forest([nodes], depth)
+
+    @classmethod
+    def from_forest(cls, trees: list[list[dict]], depth: int
+                    ) -> "TreeTraverse":
+        """Trees of flat nodes (``{"feat", "thr", "left", "right"}`` or
+        ``{"leaf"}``, root first); shorter trees are padded with
+        unreachable leaves."""
+        n = max(len(t) for t in trees)
+        shape = (len(trees), n)
+        feat = np.zeros(shape, np.int32)
+        thr = np.zeros(shape, np.float32)
+        left = np.broadcast_to(np.arange(n, dtype=np.int32), shape).copy()
+        right = left.copy()
+        leaf_class = np.zeros(shape, np.int32)
+        is_leaf = np.ones(shape, bool)
+        for t, nodes in enumerate(trees):
+            for i, nd in enumerate(nodes):
+                if "leaf" in nd:
+                    leaf_class[t, i] = nd["leaf"]
+                    continue
+                is_leaf[t, i] = False
+                feat[t, i] = nd["feat"]
+                thr[t, i] = np.float32(nd["thr"])
+                left[t, i] = nd["left"]
+                right[t, i] = nd["right"]
         return cls(feat, thr, left, right, leaf_class, is_leaf, depth)
 
+    @property
+    def n_trees(self) -> int:
+        return int(self.feat.shape[0])
+
+    @property
+    def n_classes(self) -> int:
+        """Vote lanes: a class no leaf holds gets no vote."""
+        return int(self.leaf_class.max(initial=0)) + 1
+
+    def heap(self) -> tuple:
+        """The forest as complete trees in heap layout -> (feat [T, 2^D],
+        thr [T, 2^D], leaf [T, 2^D], D).  Inner node (l, i) sits at column
+        ``2^l + i`` (column 0 unused) with children (l+1, 2i) and
+        (l+1, 2i+1); leaf i of level D at column i.  An early leaf is
+        copied down to depth D behind ``+inf`` thresholds (always left),
+        so every walk takes D compare-and-step levels.  D is the deepest
+        tree's depth, at most the ``depth + 1`` rounds ``apply`` walks; a
+        node still inner after them gives its own ``leaf_class``, as in
+        ``apply``."""
+        T = self.n_trees
+        rows = np.arange(T)[:, None]
+        node = np.zeros((T, 1), np.int64)
+        levels = []
+        while len(levels) <= self.depth and not self.is_leaf[rows, node].all():
+            leaf = self.is_leaf[rows, node]
+            levels.append((np.where(leaf, 0, self.feat[rows, node]),
+                           np.where(leaf, np.inf, self.thr[rows, node])))
+            lo = np.where(leaf, node, self.left[rows, node])
+            hi = np.where(leaf, node, self.right[rows, node])
+            node = np.stack([lo, hi], 2).reshape(T, -1)
+        D = len(levels)
+        feat = np.zeros((T, 1 << D), np.int32)
+        thr = np.zeros((T, 1 << D), np.float32)
+        for l, (f, t) in enumerate(levels):
+            feat[:, 1 << l:2 << l] = f
+            thr[:, 1 << l:2 << l] = t
+        return feat, thr, self.leaf_class[rows, node].astype(np.int32), D
+
     def apply(self, h):
+        tree = jnp.arange(self.n_trees)[None, :]
         feat = jnp.asarray(self.feat)
         thr = jnp.asarray(self.thr)
         left = jnp.asarray(self.left)
         right = jnp.asarray(self.right)
-        leaf_class = jnp.asarray(self.leaf_class)
         is_leaf = jnp.asarray(self.is_leaf)
-        nid = jnp.zeros(h.shape[0], jnp.int32)
+        nid = jnp.zeros((h.shape[0], self.n_trees), jnp.int32)
         for _ in range(self.depth + 1):
-            x_f = jnp.take_along_axis(h, feat[nid][:, None], axis=1)[:, 0]
-            child = jnp.where(x_f <= thr[nid], left[nid], right[nid])
-            nid = jnp.where(is_leaf[nid], nid, child)
-        return leaf_class[nid]
+            x_f = jnp.take_along_axis(h, feat[tree, nid], axis=1)
+            child = jnp.where(x_f <= thr[tree, nid], left[tree, nid],
+                              right[tree, nid])
+            nid = jnp.where(is_leaf[tree, nid], nid, child)
+        cls = jnp.asarray(self.leaf_class)[tree, nid]          # [B, T]
+        votes = jnp.sum(cls[:, :, None] == jnp.arange(self.n_classes),
+                        axis=1)
+        return jnp.argmax(votes, -1).astype(jnp.int32)
 
     def meta(self):
-        return {"n_nodes": len(self.feat), "depth": self.depth,
-                "params": int(len(self.feat))}
+        return {"n_trees": self.n_trees, "n_nodes": int(self.feat.size),
+                "depth": self.depth, "params": int(self.feat.size)}
 
 
 @dataclasses.dataclass(repr=False)
@@ -754,6 +821,14 @@ def _dense_widths(topology: dict) -> list[int]:
     return list(topology["widths"])
 
 
+def _tree_spec(topology: dict) -> StageSpec:
+    """A tree (``n_trees`` 1) or forest of ``nodes`` in all: ``params`` is
+    ``TreeTraverse.meta()["params"]``, ``extra`` (depth, trees)."""
+    return StageSpec("tree_traverse", 0, 0, len(topology["nodes"]),
+                     extra=(topology.get("depth", 8),
+                            topology.get("n_trees", 1)))
+
+
 def _lower_dense(algorithm: str, topology: dict) -> list[StageSpec]:
     if algorithm in ("dnn", "logreg"):
         w = _dense_widths(topology)
@@ -773,9 +848,7 @@ def _lower_dense(algorithm: str, topology: dict) -> list[StageSpec]:
             StageSpec("label_map", k, k),
         ]
     if algorithm == "tree":
-        n = len(topology["nodes"])
-        depth = topology.get("depth", 8)
-        return [StageSpec("tree_traverse", 0, 0, n, extra=(depth,))]
+        return [_tree_spec(topology)]
     raise KeyError(f"dense lowering does not map {algorithm}")
 
 
@@ -805,9 +878,7 @@ def _lower_mat(algorithm: str, topology: dict, bins: int = MAT_BINS
             StageSpec("label_map", k, k),
         ]
     if algorithm == "tree":
-        n = len(topology["nodes"])
-        depth = topology.get("depth", 8)
-        return [StageSpec("tree_traverse", 0, 0, n, extra=(depth,))]
+        return [_tree_spec(topology)]
     if algorithm == "dnn":
         # N2Net-style: each dense layer burns ~12 MATs; keep the dense
         # shapes so the accounting can read layer count

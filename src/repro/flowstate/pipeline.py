@@ -21,8 +21,8 @@ Backend selection mirrors the stateless contract
 (docs/pipeline_ir.md#flow-state-contract):
 
   * under ``backend="pallas"`` the WHOLE pipeline — every table, the
-    classifier (MLP / MAT / centroid suffixes) AND the mitigation action
-    table — lowers onto the single-launch fused kernel
+    classifier (MLP / MAT / centroid / forest suffixes) AND the
+    mitigation action table — lowers onto the single-launch fused kernel
     (kernels/fused_flow) when it matches the fused envelope, reported as
     ``"pallas-fused-flow"``; when it declines, ``fallback_reason`` keeps
     the honest reason string (surfaced by the engines' stats/journal);
@@ -43,6 +43,8 @@ half runs on Pallas reports ``"mixed"``.
 """
 
 from __future__ import annotations
+
+import time
 
 import jax
 import jax.numpy as jnp
@@ -107,15 +109,23 @@ class StatefulPipeline:
         step = None
         self.fused = False
         self.fallback_reason: str | None = None
+        # the fused launch's classifier as counters
+        # (``pallas_backend.suffix_counters``) and the perf_counter stamps
+        # of its lowering: the engines report both
+        self.fused_suffix: dict | None = None
+        self.lowered_at: tuple | None = None
         if backend == "pallas" and fuse:
+            t0 = time.perf_counter()
             step = pallas_backend.lower_stateful_fused(
                 fused_prefix, run_suffix, mit)
+            self.lowered_at = (t0, time.perf_counter())
             if step is None:
                 self.fallback_reason = \
                     pallas_backend.fused_flow_decline_reason(
                         fused_prefix, run_suffix, mit)
         if step is not None:
             self.fused = True
+            self.fused_suffix = step.suffix_counters
             self.flow_backend = self.classifier_backend = "pallas"
             self.mitigation_backend = ("pallas" if mit is not None
                                        else None)

@@ -6,6 +6,7 @@ from repro.kernels.fused_flow.kernel import (
     Plan,
     SuffixPlan,
     TablePlan,
+    forest_vmem_bytes,
     fused_flow_serve_padded,
     suffix_readout,
     suffix_verdicts,

@@ -70,7 +70,7 @@ from repro.kernels.flow_update.kernel import (
 )
 
 READOUT_MODES = ("all", "hist", "raw")
-SUFFIX_KINDS = ("mlp", "mat", "centroid")
+SUFFIX_KINDS = ("mlp", "mat", "centroid", "forest")
 
 # verdict sentinel for a dropped packet (flowstate.mitigation.MITIGATED)
 _MITIGATED = -1
@@ -92,14 +92,16 @@ class TablePlan(NamedTuple):
 class SuffixPlan(NamedTuple):
     """Static description of the in-kernel classifier."""
 
-    kind: str                  # mlp | mat | centroid
+    kind: str                  # mlp | mat | centroid | forest
     num_classes: int           # score lanes before any LabelMap rewrite
     n_layers: int = 0          # mlp: layer count
-    lane: int = 0              # mlp: snapped lane
+    lane: int = 0              # mlp: snapped lane; forest: lane tile
     n_features: int = 0        # mat: real (unpadded) feature count
     use_min: bool = False      # mat/centroid: argmin vs argmax
     n_centroids: int = 0       # centroid: real centroid count
     feature_idx: tuple = ()    # centroid: optional static FeatureSelect
+    n_trees: int = 0           # forest: tree count
+    depth: int = 0             # forest: levels of the complete trees
 
 
 class MitPlan(NamedTuple):
@@ -122,7 +124,7 @@ class Plan(NamedTuple):
 
 
 # operand count per suffix kind (see the layout walked by _serve_kernel)
-N_SUFFIX_OPS = {"mlp": 2, "mat": 3, "centroid": 2}
+N_SUFFIX_OPS = {"mlp": 2, "mat": 3, "centroid": 2, "forest": 3}
 # VMEM operands per flow table: init, add, val, rank, fresh
 N_TABLE_OPS = 5
 
@@ -191,6 +193,113 @@ def _arg_reduce(scores, n_real: int, use_min: bool):
     return jnp.argmax(scores, axis=1).astype(jnp.int32)
 
 
+def _level_windows(depth: int, n_cols: int, tile: int) -> list:
+    """Per level of a heap-laid tree: (first column, width, whether the
+    packet's column is ``2^l + i`` or ``i``).  The levels that fit the
+    first ``tile`` columns share that window; each deeper level ``l`` is
+    its own window ``[2^l, 2^(l+1))``, tile-aligned."""
+    w0 = min(tile, n_cols)
+    return [(0, w0, True) if 2 << l <= w0 else (1 << l, 1 << l, False)
+            for l in range(depth)]
+
+
+def _bf16_parts(z):
+    """f32 -> (hi, mid, lo) bfloat16 with ``(hi + mid) + lo == z`` exactly
+    in f32: each part takes the next 8 significant bits of the rest, so
+    every residual is exact and the last part fits bfloat16."""
+    hi = z.astype(jnp.bfloat16)
+    rest = z - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _first_argmax(votes, n_real: int):
+    """Lane of the most votes among the first ``n_real``, ties to the
+    lowest lane, from a max and a min over lanes (no reliance on how an
+    arg-reduce breaks ties)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, votes.shape, 1)
+    real = lane < n_real
+    best = jnp.max(jnp.where(real, votes, -1.0), axis=1, keepdims=True)
+    first = jnp.where(real & (votes == best), lane.astype(jnp.float32),
+                      float(votes.shape[1]))
+    return jnp.min(first, axis=1).astype(jnp.int32)
+
+
+def forest_votes(z, feat_ref, thr_ref, leaf_ref, sp: SuffixPlan):
+    """Readout rows [B, lane] -> per-class vote counts [B, lane] f32.
+
+    The parameters are refs of complete trees in heap layout, one [1, N]
+    row per tree (``TreeTraverse.heap``): ``feat_ref``/``thr_ref``
+    [T, 1, N] hold inner node (l, i) at column ``2^l + i``, ``leaf_ref``
+    [T, 1, N] leaf i's class at column i (a tree is a leading index:
+    Mosaic has no layout for a dynamic single-row slice of [T, N]).
+
+    A loop over the trees reads one tree's level windows
+    (``_level_windows``).  Per window every node's feature is selected for
+    every packet by one-hot matmuls of the readout's three bfloat16 parts
+    (``_bf16_parts``: each product is one part times 1, so their sum is
+    an exact f32 copy, in half the MXU passes of an f32 matmul at
+    ``HIGHEST``) and compared with the node's threshold (``<=`` goes
+    left); per level each packet keeps its own node's decision and steps
+    to child ``2i`` or ``2i + 1``.  After ``depth`` levels its leaf's
+    class adds a vote."""
+    n_pkt, lane = z.shape
+    n_cols = feat_ref.shape[2]
+    parts = _bf16_parts(z)
+    windows = _level_windows(sp.depth, n_cols, sp.lane)
+    k_iota = {w: jax.lax.broadcasted_iota(jnp.int32, (lane, w), 0)
+              for _, w, _ in windows}
+    c_iota = {w: jax.lax.broadcasted_iota(jnp.int32, (n_pkt, w), 1)
+              for _, w, _ in windows}
+    leaf_iota = jax.lax.broadcasted_iota(jnp.int32, (n_pkt, n_cols), 1)
+    cls_iota = jax.lax.broadcasted_iota(jnp.int32, (n_pkt, lane), 1)
+
+    def tree(t, votes):
+        goes_left = {}
+        for lo, w, _ in windows:
+            if lo in goes_left:
+                continue
+            feat = feat_ref[t, :, lo:lo + w]
+            onehot = (k_iota[w] == feat).astype(jnp.bfloat16)
+            hi, mid, low = (jnp.dot(p, onehot,
+                                    preferred_element_type=jnp.float32)
+                            for p in parts)
+            goes_left[lo] = (hi + mid) + low <= thr_ref[t, :, lo:lo + w]
+        idx = jnp.zeros((n_pkt, 1), jnp.int32)
+        for l, (lo, w, shared) in enumerate(windows):
+            col = idx + (1 << l) if shared else idx
+            mine = (c_iota[w] == col) & goes_left[lo]
+            left = jnp.max(mine.astype(jnp.float32), axis=1, keepdims=True)
+            idx = 2 * idx + 1 - left.astype(jnp.int32)
+        leaf = leaf_ref[t]
+        cls = jnp.sum(jnp.where(leaf_iota == idx, leaf, 0.0), axis=1,
+                      keepdims=True)
+        return votes + (cls_iota == cls.astype(jnp.int32)).astype(
+            jnp.float32)
+
+    return jax.lax.fori_loop(0, sp.n_trees, tree,
+                             jnp.zeros((n_pkt, lane), jnp.float32))
+
+
+def forest_vmem_bytes(n_trees: int, depth: int, batch: int,
+                      lane: int = LANE) -> int:
+    """VMEM of the forest suffix at TPU tiling: its three [T, 1, N]
+    parameter blocks (N = 2^depth columns in whole lane tiles, each tree's
+    row padded to a sublane tile), double-buffered by the pipeline, and
+    one tree's temporaries in ``forest_votes``: each level window's
+    decisions kept for the walk, the widest window's selected features
+    and one-hot, the leaf select, the readout, its bfloat16 parts and the
+    votes."""
+    n = max(lane, -(-(1 << depth) // lane) * lane)
+    params = 2 * 3 * n_trees * 8 * n * 4
+    windows = _level_windows(depth, n, lane)
+    kept = sum({lo: w for lo, w, _ in windows}.values())
+    widest = max((w for _, w, _ in windows), default=0)
+    temps = (batch * (kept + 2 * widest + n + 4 * lane)
+             + lane * widest) * 4
+    return params + temps
+
+
 def suffix_verdicts(z, arrays: tuple, sp: SuffixPlan):
     """Readout rows [B, n_in] -> int32 class ids, per ``sp.kind``.
 
@@ -206,7 +315,10 @@ def suffix_verdicts(z, arrays: tuple, sp: SuffixPlan):
         ``mat_lut._kernel``;
       * centroid: (cent [K8, F_pad] zero-padded, lmap [1, K_pad]) —
         per-centroid squared distances (zero pad lanes add exact zeros),
-        +inf-masked arg-reduce, LabelMap rewrite.
+        +inf-masked arg-reduce, LabelMap rewrite;
+      * forest:   REFS (feat [T, 1, N] int32, thr [T, 1, N] f32, leaf
+        [T, 1, N] f32) of complete heap-laid trees — ``forest_votes``,
+        then the most votes, ties to the lowest class (``_first_argmax``).
 
     Rows that are all zero (ragged padding) classify to some fixed class
     — the engine slices those verdicts off."""
@@ -258,6 +370,9 @@ def suffix_verdicts(z, arrays: tuple, sp: SuffixPlan):
                          constant_values=fill)
         ids = _arg_reduce(scores, sp.n_centroids, sp.use_min)
         return _label_rewrite(ids, lmap)
+    if sp.kind == "forest":
+        zp = jnp.pad(z, ((0, 0), (0, sp.lane - z.shape[1])))
+        return _first_argmax(forest_votes(zp, *arrays, sp), sp.num_classes)
     raise KeyError(f"suffix kind must be one of {SUFFIX_KINDS}")
 
 
@@ -367,7 +482,10 @@ def _serve_kernel(*refs, plan: Plan):
     z = jnp.concatenate(zs, axis=1) if nt > 1 else zs[0]
 
     cur = N_TABLE_OPS * nt
-    s_arrays = tuple(r[...] for r in vin[cur:cur + n_sfx])
+    s_refs = vin[cur:cur + n_sfx]
+    # the forest reads one tree's row per step of its loop: it takes refs
+    s_arrays = (s_refs if plan.suffix.kind == "forest"
+                else tuple(r[...] for r in s_refs))
     cur += n_sfx
     verd = suffix_verdicts(z, s_arrays, plan.suffix)[:, None]
 
@@ -423,6 +541,9 @@ def fused_flow_serve_padded(*ops, plan: Plan, interpret: bool = False):
         out_shape=out_shape,
         compiler_params=compiler_params(
             sum(a.size * a.dtype.itemsize for a in vin) * 2
-            + sum(s.size * 4 for s in out_shape) * 2 + b_pad * b_pad * 4),
+            + sum(s.size * 4 for s in out_shape) * 2 + b_pad * b_pad * 4
+            + (forest_vmem_bytes(plan.suffix.n_trees, plan.suffix.depth,
+                                 b_pad, plan.suffix.lane)
+               if plan.suffix.kind == "forest" else 0)),
         interpret=interpret,
     )(*ops)

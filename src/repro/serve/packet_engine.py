@@ -81,6 +81,9 @@ class ServeStats:
     # serving); under overlap this is much smaller than wall_s
     dispatch_s: float = 0.0
     backend: str = "interpret"     # engine the compiled pipeline runs on
+    # the fused flow launch's classifier (StatefulPipeline.fused_suffix):
+    # kind, trees, depth, node visits per packet, packed parameter bytes
+    fused_suffix: dict = dataclasses.field(default_factory=dict)
     depth: int = 1                 # dispatch-pipeline depth (in-flight cap)
     shards: int = 1                # devices serving (ShardedPacketServeEngine)
     # trailing window of per-batch latencies: bounded so a long-running
@@ -156,6 +159,7 @@ class ServeStats:
             "lat_p99_ms": round(self.lat_p99_ms, 4),
             "backend": self.backend,
             "backend_batches": self.backend_batches,
+            "fused_suffix": dict(self.fused_suffix),
             "depth": self.depth,
             "shards": self.shards,
             "swaps": self.swaps,
@@ -327,6 +331,7 @@ class PacketServeEngine:
         self._pending_swap: tuple | None = None
         self.stats_ = ServeStats(backend=self.backend, depth=self.depth)
         self._init_telemetry(telemetry, requested_backend)
+        self._note_lowering(pipeline)
         with self._span("serve.warm_up", cat="compile",
                         backend=self.backend):
             self._warm_up()
@@ -750,9 +755,21 @@ class PacketServeEngine:
                 old_backend=old_backend, new_backend=self.backend,
                 engine=type(self).__name__)
 
+    def _note_lowering(self, pipeline) -> None:
+        """Take the fused launch's classifier counters into ``stats()``
+        and its lowering into the ring as a one-off ``serve.lower`` span
+        (on the pipeline's own stamps) with the counters as arguments."""
+        counters = getattr(pipeline, "fused_suffix", None) or {}
+        self.stats_.fused_suffix = dict(counters)
+        stamps = getattr(pipeline, "lowered_at", None)
+        if counters and stamps and self._tel is not None:
+            self._tel.tracer.record("serve.lower", *stamps, cat="compile",
+                                    args=dict(counters))
+
     def _install_swap(self, payload: dict) -> None:
         pipeline = payload["pipeline"]
         self._carry_state(pipeline)
+        self._note_lowering(pipeline)
         self.pipeline = pipeline
         self.backend = _pipeline_backend(pipeline)
         self._backend_key = _backend_stats_key(pipeline, self.backend)

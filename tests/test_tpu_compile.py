@@ -108,14 +108,14 @@ def test_stateless_kernel_compiles(kernel, one_chip, on_tpu):
 
 
 FORMS = ("mlp", "mat", "centroid", "mit-shared", "mit-separate",
-         "two-table")
+         "two-table", "forest")
 
 
 def _form_stages(form: str, *, n_slots: int, seed: int):
     """A stateful pipeline of each form the fused flow launch serves: the
     ``traffic.flow_feature_stages`` prefix and a suffix with seeded random
     parameters (real shapes, no training) — an MLP, MAT or centroid
-    suffix; the MAT suffix with a drop table of the flow table's slot
+    suffix, or a forest of 32 complete trees of depth 10; the MAT suffix with a drop table of the flow table's slot
     count (``mit-shared``) or a rate-limit table of half of it
     (``mit-separate``); or a second, port-keyed table feeding one MLP
     (``two-table``)."""
@@ -144,6 +144,22 @@ def _form_stages(form: str, *, n_slots: int, seed: int):
                     rng.random((5, 4)).astype(np.float32)),
                 stageir.Reduce("argmin"),
                 stageir.LabelMap(np.asarray([0, 1, 0, 1, 1], np.int32))]
+    if form == "forest":
+        T, D = 32, 10
+        n_inner = (1 << D) - 1
+        nodes = np.arange(2 * n_inner + 1)
+        inner = nodes < n_inner
+
+        def per_tree(a):
+            return np.tile(a, (T, 1))
+
+        feat = per_tree(np.zeros(len(nodes), np.int32))
+        feat[:, :n_inner] = rng.integers(0, n_in, (T, n_inner))
+        thr = rng.random(feat.shape).astype(np.float32)
+        return [fk, ru, ws, stageir.TreeTraverse(
+            feat, thr, per_tree(np.where(inner, 2 * nodes + 1, nodes)),
+            per_tree(np.where(inner, 2 * nodes + 2, nodes)),
+            rng.integers(0, 2, feat.shape), per_tree(~inner), D)]
     if form == "two-table":
         spec2 = FlowStateSpec(n_slots=n_slots // 4, n_counters=2,
                               n_ewma=1, hist_sizes=(4,))
