@@ -12,14 +12,18 @@ verdicts sliced off, so steady-state serving never re-traces.
 Overlap pipelining (docs/pipeline_ir.md#serving-performance-contract):
 the engine keeps up to ``depth`` batches in flight — batch N+1 is staged
 (copied into a reusable ring of pinned staging buffers) and dispatched
-while batch N still computes; results are materialized lazily, only when
-``flush()``/stream consumption actually needs them.  Compiled pipelines
-expose ``dispatch`` (launch, no device→host copy) and JAX's async
-dispatch does the overlap; steady-state serving performs zero per-batch
-staging allocations.  ``ServeStats`` separates host dispatch time
-(``dispatch_s``) from per-batch pipeline latency (dispatch → result
-ready) and accumulates ``wall_s`` as the *active serving span*, so pkt/s
-stays honest under overlap instead of crediting hidden device time.
+while batch N still computes.  Compiled pipelines expose ``dispatch``
+(launch, no blocking device→host copy) and JAX's async dispatch does the
+overlap.  A dispatch also starts the copy of its verdicts to the host
+(``copy_to_host_async`` on a lazy device result), so that copy runs while
+later batches are staged and launched; results are still *taken* only
+when ``flush()``/stream consumption needs them, in FIFO (arrival) order
+(``stats()["early_copies"]`` counts the batches whose copy began early).
+Steady-state serving performs zero per-batch staging allocations.
+``ServeStats`` separates host dispatch time (``dispatch_s``) from
+per-batch pipeline latency (dispatch → result ready) and accumulates
+``wall_s`` as the *active serving span*, so pkt/s stays honest under
+overlap instead of crediting hidden device time.
 
 Stateful serving: a ``StatefulPipeline`` threads a per-flow register file
 (``FlowState``) through every batch.  The engine owns the state between
@@ -80,6 +84,9 @@ class ServeStats:
     # host time spent staging + launching batches (the synchronous part of
     # serving); under overlap this is much smaller than wall_s
     dispatch_s: float = 0.0
+    # batches whose verdict copy to the host began at dispatch (a lazy
+    # device result); a numpy result is already on the host and not counted
+    early_copies: int = 0
     backend: str = "interpret"     # engine the compiled pipeline runs on
     # the fused flow launch's classifier (StatefulPipeline.fused_suffix):
     # kind, trees, depth, node visits per packet, packed parameter bytes
@@ -153,6 +160,7 @@ class ServeStats:
             "pad_packets": self.pad_packets,
             "wall_s": round(self.wall_s, 6),
             "dispatch_s": round(self.dispatch_s, 6),
+            "early_copies": self.early_copies,
             "pkt_per_s": round(self.pkt_per_s, 1),
             "lat_p50_ms": round(self.lat_p50_ms, 4),
             "lat_p95_ms": round(self.lat_p95_ms, 4),
@@ -379,6 +387,10 @@ class PacketServeEngine:
                 "serve_packets_total", "real packets dispatched").default,
             "batches": m.counter(
                 "serve_batches_total", "micro-batches dispatched").default,
+            "early_copies": m.counter(
+                "serve_early_host_copies_total",
+                "batches whose verdict copy to the host began at "
+                "dispatch").default,
             "pad": m.counter(
                 "serve_pad_packets_total",
                 "zero rows added to fill fixed batch shapes").default,
@@ -663,8 +675,21 @@ class PacketServeEngine:
         if self._tel is not None:
             with self._annotate("serve.record", batch=k):
                 self._record_dispatch(rows, n, pad, d.t1 - d.t0)
-        self._inflight.append(_InFlight(n, out, d.t0, ready, k))
+        self._enqueue(_InFlight(n, out, d.t0, ready, k))
         return n
+
+    def _enqueue(self, f: _InFlight) -> None:
+        """Append a dispatched batch to the in-flight FIFO, first starting
+        the copy of its verdicts to the host when the result is a lazy
+        device array: the fetch then finds the copy done, or under way,
+        instead of starting it and waiting out its latency."""
+        start_copy = getattr(f.out, "copy_to_host_async", None)
+        if start_copy is not None:
+            start_copy()
+            self.stats_.early_copies += 1
+            if self._tel is not None:
+                self._tm["early_copies"].inc(1)
+        self._inflight.append(f)
 
     # ---------------------------------------------------------- hot swap
 
@@ -802,7 +827,7 @@ class PacketServeEngine:
         """Materialize the oldest in-flight batch (FIFO: arrival order)."""
         f = self._inflight.popleft()
         with self._annotate("serve.fetch", batch=f.batch):
-            v = np.asarray(f.out)      # blocks until the result exists
+            v = np.asarray(f.out)      # blocks until the host copy is in
             end = f.ready if f.ready is not None else time.perf_counter()
             self.stats_.batch_lat_s.append(end - f.t0)
             if self._mark is not None:

@@ -261,7 +261,7 @@ class ShardedPacketServeEngine(PacketServeEngine):
                              + self._hash_slot_np(keys[:m], n_slots))
                 self._record_dispatch(rows, m, self.max_batch - m,
                                       d.t1 - d.t0, slots=slots)
-        self._inflight.append(_InFlight(m, out, d.t0, None, k, perm=perm))
+        self._enqueue(_InFlight(m, out, d.t0, None, k, perm=perm))
         return m
 
     def _launch_stateful(self, buf: np.ndarray, valid: np.ndarray):

@@ -125,6 +125,65 @@ def test_view_returning_pipeline_verdicts_survive_buffer_reuse():
     eng.flush()
     # the earlier verdicts must be untouched by the ring reuse
     np.testing.assert_array_equal(first, np.arange(40))
+    # a numpy result is already on the host: no copy was started early
+    assert eng.stats()["batches"] == 7
+    assert _early_copies(eng) == 0
+
+
+def _early_copies(eng):
+    """``stats()["early_copies"]`` after checking its telemetry mirror."""
+    tel = eng.telemetry().metrics.get("serve_early_host_copies_total")
+    assert tel.value() == eng.stats()["early_copies"]
+    return eng.stats()["early_copies"]
+
+
+class _LazyVerdicts:
+    """Stands in for a device result: logs when its host copy is started
+    and when it is taken."""
+
+    def __init__(self, rows, tag, log):
+        self._v = rows[:, 0].astype(np.int32)   # the staging row is reused
+        self._tag, self._log = tag, log
+
+    def copy_to_host_async(self):
+        self._log.append(("copy", self._tag))
+
+    def __array__(self, dtype=None, copy=None):
+        self._log.append(("fetch", self._tag))
+        return self._v
+
+
+class _LazyPipeline:
+    def __init__(self):
+        self.log, self.n = [], 0
+
+    def __call__(self, x):
+        self.log.append(("dispatch", self.n))
+        self.n += 1
+        return _LazyVerdicts(x, self.n - 1, self.log)
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_verdict_copy_starts_at_dispatch_not_at_fetch(depth):
+    """Each batch's copy to the host is started right after its dispatch,
+    before any later batch is dispatched and before its own fetch."""
+    pipe = _LazyPipeline()
+    eng = PacketServeEngine(pipe, feature_dim=2, max_batch=4, depth=depth)
+    pipe.log.clear()                     # the warm-up batch
+    pipe.n = 0
+    eng.submit(_tagged(20))
+    np.testing.assert_array_equal(eng.flush(), np.arange(20))
+    log = pipe.log
+    for k in range(5):
+        d, c, f = (log.index((e, k)) for e in ("dispatch", "copy", "fetch"))
+        assert c == d + 1 < f
+    # the fetches still wait for the ring: at depth d, batch k is taken
+    # after batch k + d - 1 is dispatched (arrival order kept)
+    for k in range(5 - depth + 1):
+        assert log.index(("fetch", k)) > log.index(("copy", k + depth - 1))
+    assert [e for e in log if e[0] == "fetch"] == [("fetch", k)
+                                                   for k in range(5)]
+    assert _early_copies(eng) == eng.stats()["batches"] == 5
 
 
 def test_requested_pallas_unavailable_reports_interpreter(monkeypatch):
@@ -198,6 +257,29 @@ def test_stateful_ragged_interleavings_match_single_pass(data):
     np.testing.assert_array_equal(np.asarray(eng.state.regs),
                                   np.asarray(ref_state.regs))
     np.testing.assert_array_equal(feats, np.asarray(ref_feats))
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_stateful_early_copies_match_synchronous_run(rng, depth):
+    """On a jitted stateful pipeline every batch's copy starts at
+    dispatch, and the verdicts and registers are those of a strictly
+    synchronous run, batch by batch, in arrival order."""
+    X = _flow_packets(rng, 90)
+    eng = PacketServeEngine(_flow_pipeline(), feature_dim=2, max_batch=8,
+                            depth=depth)
+    for lo in range(0, 90, 30):
+        eng.submit(X[lo:lo + 30])
+    got = eng.flush()
+    ref_pipe = _flow_pipeline()
+    state, ref = ref_pipe.init_state(), []
+    for lo in range(0, 90, 8):           # the engine's batches, each taken
+        state, v = ref_pipe(state, X[lo:lo + 8])   # before the next
+        ref.append(v)
+    np.testing.assert_array_equal(got, np.concatenate(ref))
+    np.testing.assert_array_equal(np.asarray(eng.state.regs),
+                                  np.asarray(state.regs))
+    assert eng.stats()["batches"] == 12
+    assert _early_copies(eng) == 12
 
 
 def test_engine_initializes_and_threads_state(rng):

@@ -126,6 +126,27 @@ def test_sharded_stateful_parity_one_shard(rng):
     assert eng.state.occupied == base.state.occupied
 
 
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_sharded_early_copies_keep_arrival_order(rng, depth):
+    """The sharded step's result starts its copy to the host at dispatch,
+    batch by batch, and the verdicts are a synchronous engine's, in
+    arrival order."""
+    X = _flow_packets(rng, 100)
+    eng = ShardedPacketServeEngine(_flow_pipeline(), feature_dim=2,
+                                   max_batch=16, depth=depth, min_shards=1)
+    ref = PacketServeEngine(_flow_pipeline(), feature_dim=2, max_batch=16,
+                            depth=1)
+    for chunk in np.array_split(X, 3):
+        eng.submit(chunk)
+        ref.submit(chunk)
+    np.testing.assert_array_equal(eng.flush(), ref.flush())
+    s = eng.stats()
+    assert s["batches"] == ref.stats()["batches"] == 7
+    assert s["early_copies"] == s["batches"]
+    tel = eng.telemetry().metrics.get("serve_early_host_copies_total")
+    assert tel.value() == s["batches"]
+
+
 # ------------------------------------------------- stream edge behavior
 
 
@@ -365,6 +386,8 @@ _PLACEMENT_SCRIPT = textwrap.dedent("""
         assert (got < 0).any() == mitigated
         resharded = eng.telemetry().metrics.get("serve_resharded_step_args")
         assert resharded.value() == 0, resharded.value()
+        s = eng.stats()
+        assert s["early_copies"] == s["batches"] > 0, s
     print("PLACEMENT-OK")
 """)
 
